@@ -1,10 +1,10 @@
 """jit'd public wrappers around the Pallas kernels.
 
 Each op prepares kernel-friendly layouts, dispatches to the Pallas kernel
-(interpret mode on CPU — the TPU fast path is the same call with
-interpret=False), and exposes a differentiable version via jax.custom_vjp
-whose backward pass is the grad of the pure-jnp oracle algorithm (recompute
-— a standard production pattern: optimized forward, reference backward).
+(compiled for the TPU it lowers to, interpreted on CPU — ``_pallas``), and
+exposes a differentiable version via jax.custom_vjp whose backward pass is
+the grad of the pure-jnp oracle algorithm (recompute — a standard
+production pattern: optimized forward, reference backward).
 """
 from __future__ import annotations
 
@@ -19,11 +19,14 @@ from repro.kernels.ssd_scan import ssd_scan_chunked
 from repro.models.attention import chunked_causal_attention
 from repro.models.ssm import ssd_chunked
 
-_ON_TPU = False  # flipped by deployment config; this container is CPU-only
 
-
-def _interp() -> bool:
-    return not _ON_TPU
+def _pallas(kernel, *args, **static):
+    """``kernel(*args, **static)`` with ``interpret`` chosen per lowering
+    platform: the Mosaic kernel wherever the call lowers for a TPU, the
+    Pallas interpreter on CPU (the only backend that needs it)."""
+    return jax.lax.platform_dependent(
+        *args, cpu=functools.partial(kernel, interpret=True, **static),
+        default=functools.partial(kernel, interpret=False, **static))
 
 
 # ---------------------------------------------------------------------------
@@ -32,8 +35,8 @@ def _interp() -> bool:
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def flash_attention(q, k, v, block_q: int = 512, block_kv: int = 512):
-    return flash_attention_fwd(q, k, v, block_q=block_q, block_kv=block_kv,
-                               interpret=_interp())
+    return _pallas(flash_attention_fwd, q, k, v, block_q=block_q,
+                   block_kv=block_kv)
 
 
 def _fa_fwd(q, k, v, block_q, block_kv):
@@ -80,10 +83,10 @@ def ssd_scan(x, dt, A, B, C, *, chunk: int = 128):
         a = jnp.moveaxis(a, 2, 1)                  # [b,h,s,*]
         return a.reshape(b * h, nc, chunk, *feat)
     xdt_c = chunked(xdt, (p,))
-    dA_c = chunked(dA, ())
+    dA_c = chunked(dA, ())[:, :, None, :]           # [b*h, nc, 1, L]
     B_c = chunked(Bh.astype(jnp.float32), (n,))
     C_c = chunked(Ch.astype(jnp.float32), (n,))
-    y = ssd_scan_chunked(xdt_c, dA_c, B_c, C_c, interpret=_interp())
+    y = _pallas(ssd_scan_chunked, xdt_c, dA_c, B_c, C_c)
     y = y.reshape(b, h, s2, p)
     y = jnp.moveaxis(y, 1, 2)[:, :s]
     return y.astype(x.dtype)
@@ -102,5 +105,5 @@ def rglru_recurrence(a, b, *, block_s: int = 256, block_w: int = 512):
     bw = min(block_w, w)
     while w % bw:
         bw //= 2
-    return rglru_scan_pallas(a, b, block_s=max(bs, 1), block_w=max(bw, 1),
-                             interpret=_interp())
+    return _pallas(rglru_scan_pallas, a, b, block_s=max(bs, 1),
+                   block_w=max(bw, 1))

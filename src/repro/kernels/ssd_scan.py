@@ -15,7 +15,8 @@ matrix, and the [N, P] state — with the default L=128, N=128, P=64 this is
 a multiple of the 128-lane MXU tiling.
 
 Inputs are pre-chunked by ops.ssd_scan: xdt [BH, NC, L, P] (x·dt),
-dA [BH, NC, L] (dt·A), Bm/Cm [BH, NC, L, N] (group-expanded).
+dA [BH, NC, 1, L] (dt·A, one lane row per chunk, so its block's last two
+dims are the array's), Bm/Cm [BH, NC, L, N] (group-expanded).
 Validated in interpret mode against repro.kernels.ref.ssd_ref.
 """
 from __future__ import annotations
@@ -37,16 +38,29 @@ def _ssd_kernel(xdt_ref, dA_ref, b_ref, c_ref, o_ref, state_scr, *,
         state_scr[...] = jnp.zeros_like(state_scr)
 
     xdt = xdt_ref[0, 0].astype(jnp.float32)           # [L, P]
-    dA = dA_ref[0, 0].astype(jnp.float32)             # [L]
+    dA = dA_ref[0, 0].astype(jnp.float32)             # [1, L]
     Bm = b_ref[0, 0].astype(jnp.float32)              # [L, N]
     Cm = c_ref[0, 0].astype(jnp.float32)              # [L, N]
 
-    cs = jnp.cumsum(dA)                               # [L]
-    # within-chunk decay matrix: L[i,j] = exp(cs_i - cs_j), i >= j
-    diff = cs[:, None] - cs[None, :]
+    # Mosaic lowers no cumsum, so the inclusive prefix sum runs on the MXU
+    # against the lower-triangular ones matrix, once per orientation: that
+    # also spares relaying the [1, L] row out as an [L, 1] column.
+    # HIGHEST keeps the products in f32.
     li = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     lj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    Lmat = jnp.where(li >= lj, jnp.exp(diff), 0.0)
+    tril = (li >= lj).astype(jnp.float32)
+    dA_rows = jnp.broadcast_to(dA, (chunk, chunk))    # [r, k] = dA_k
+    nt = (((1,), (1,)), ((), ()))
+    cs_i = jax.lax.dot_general(tril, dA_rows, nt,     # [i, r] = cs_i
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
+    cs_j = jax.lax.dot_general(dA_rows, tril, nt,     # [r, j] = cs_j
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
+    cs = cs_i[:, :1]                                  # [L, 1]
+    cs_end = jnp.sum(dA)                              # chunk total
+    # within-chunk decay matrix: L[i,j] = exp(cs_i - cs_j), i >= j
+    Lmat = jnp.where(li >= lj, jnp.exp(cs_i - cs_j), 0.0)
 
     S = jax.lax.dot_general(Cm, Bm, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)  # [L, L]
@@ -56,11 +70,11 @@ def _ssd_kernel(xdt_ref, dA_ref, b_ref, c_ref, o_ref, state_scr, *,
     state = state_scr[...]                            # [N, P]
     y_off = jax.lax.dot_general(Cm, state, (((1,), (0,)), ((), ())),
                                 preferred_element_type=jnp.float32)
-    y_off = y_off * jnp.exp(cs)[:, None]
+    y_off = y_off * jnp.exp(cs)
 
-    decay_to_end = jnp.exp(cs[-1] - cs)               # [L]
-    state_new = (jnp.exp(cs[-1]) * state
-                 + jax.lax.dot_general(Bm * decay_to_end[:, None], xdt,
+    decay_to_end = jnp.exp(cs_end - cs)               # [L, 1]
+    state_new = (jnp.exp(cs_end) * state
+                 + jax.lax.dot_general(Bm * decay_to_end, xdt,
                                        (((0,), (0,)), ((), ())),
                                        preferred_element_type=jnp.float32))
     state_scr[...] = state_new
@@ -70,7 +84,7 @@ def _ssd_kernel(xdt_ref, dA_ref, b_ref, c_ref, o_ref, state_scr, *,
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def ssd_scan_chunked(xdt: jax.Array, dA: jax.Array, Bm: jax.Array,
                      Cm: jax.Array, *, interpret: bool = True) -> jax.Array:
-    """xdt [BH, NC, L, P]; dA [BH, NC, L]; Bm/Cm [BH, NC, L, N] ->
+    """xdt [BH, NC, L, P]; dA [BH, NC, 1, L]; Bm/Cm [BH, NC, L, N] ->
     y [BH, NC, L, P]."""
     bh, nc, l, p = xdt.shape
     n = Bm.shape[-1]
@@ -79,15 +93,12 @@ def ssd_scan_chunked(xdt: jax.Array, dA: jax.Array, Bm: jax.Array,
     def ix(b, c):
         return (b, c, 0, 0)
 
-    def ix3(b, c):
-        return (b, c, 0)
-
     return pl.pallas_call(
         functools.partial(_ssd_kernel, chunk=l),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, l, p), ix),
-            pl.BlockSpec((1, 1, l), ix3),
+            pl.BlockSpec((1, 1, 1, l), ix),
             pl.BlockSpec((1, 1, l, n), ix),
             pl.BlockSpec((1, 1, l, n), ix),
         ],

@@ -28,7 +28,6 @@ from repro.config import (
     shape_applicable,
 )
 from repro.config.base import TrainConfig
-from repro.parallel.compat import set_mesh
 from repro.launch.hlo_analysis import collective_summary
 from repro.launch.mesh import make_production_mesh
 from repro.launch.specs import (
@@ -137,7 +136,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
         out_sh = (named(mesh, params_p), named(mesh, opt_p), None)
         fn = jax.jit(train_step, in_shardings=in_sh, out_shardings=out_sh,
                      donate_argnums=(0, 1))
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = fn.lower(params_s, opt_s, batch_s)
         tokens = shape.global_batch * shape.seq_len
         result["model_flops"] = 6.0 * model_cfg.active_param_count() * tokens
@@ -152,7 +151,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
 
         fn = jax.jit(prefill_step,
                      in_shardings=(named(mesh, params_p), named(mesh, inp_p)))
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = fn.lower(params_s, inp_s)
         tokens = shape.global_batch * shape.seq_len
         result["model_flops"] = 2.0 * model_cfg.active_param_count() * tokens
@@ -169,7 +168,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
                      in_shardings=(named(mesh, params_p), named(mesh, cache_p),
                                    named(mesh, inp_p), None),
                      donate_argnums=(1,))
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = fn.lower(params_s, cache_s, inp_s, pos_s)
         result["model_flops"] = 2.0 * model_cfg.active_param_count() * shape.global_batch
 

@@ -1,43 +1,34 @@
 """Production mesh construction.
 
-A FUNCTION (not a module-level constant) so importing never touches jax
+FUNCTIONS (not module-level constants) so importing never touches jax
 device state. Pod = AI-DC: the "pod" axis is the long-haul OTN boundary that
-MatchRDMA manages; "data" x "model" is the intra-DC 2D layout.
-
-``jax.sharding.AxisType`` only exists on newer JAX (>= 0.5); on older
-installs meshes are built without explicit axis types (every axis was
-implicitly Auto there, so behavior is unchanged).
+MatchRDMA manages; "data" x "model" is the intra-DC 2D layout. Every axis is
+``AxisType.Auto``: GSPMD partitions what ``shard_map`` leaves automatic.
 """
 from __future__ import annotations
 
 import jax
-
-try:  # JAX >= 0.5
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - depends on installed JAX
-    AxisType = None
+import numpy as np
+from jax.sharding import AxisType, Mesh
 
 
-def _axis_type_kwargs(num_axes: int) -> dict:
-    """axis_types kwargs when the installed JAX supports them."""
-    if AxisType is None:
-        return {}
-    return {"axis_types": (AxisType.Auto,) * num_axes}
+def make_mesh(shape, axes, devices=None) -> Mesh:
+    """An all-Auto mesh of ``shape`` over ``devices`` (default: the
+    devices ``jax.make_mesh`` picks from ``jax.devices()``)."""
+    shape, axes = tuple(shape), tuple(axes)
+    types = (AxisType.Auto,) * len(axes)
+    if devices is not None:
+        return Mesh(np.asarray(devices).reshape(shape), axes,
+                    axis_types=types)
+    return jax.make_mesh(shape, axes, axis_types=types)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return make_mesh(shape, axes)
 
 
 def make_mesh_for(par, devices=None):
     """Mesh from a ParallelConfig (tests / small runs pass explicit devices)."""
-    import numpy as np
-    shape = par.mesh_shape()
-    axes = par.axis_names()
-    if devices is not None:
-        from jax.sharding import Mesh
-        arr = np.asarray(devices).reshape(shape)
-        return Mesh(arr, axes, **_axis_type_kwargs(len(axes)))
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return make_mesh(par.mesh_shape(), par.axis_names(), devices)

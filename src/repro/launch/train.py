@@ -21,7 +21,6 @@ import numpy as np
 from repro.config import get_model_config
 from repro.config.base import ParallelConfig, TrainConfig
 from repro.launch.mesh import make_mesh_for
-from repro.parallel.compat import set_mesh
 from repro.models import build_model
 from repro.parallel.sharding import ShardingRules, named
 from repro.train.checkpoint import CheckpointManager
@@ -74,7 +73,7 @@ def main(argv=None):
     model, model_cfg, mesh, rules, step_fn = build(
         args.arch, args.smoke, par, train_cfg)
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = model.init(jax.random.PRNGKey(train_cfg.seed))
         opt = init_adam(params, par.opt_state_dtype)
         pspecs = rules.params_tree_specs(params)

@@ -59,10 +59,9 @@ def apply_moe(p: dict, x: jax.Array, cfg: ModelConfig) -> Tuple[jax.Array, dict]
         # over the batch axes makes routing shard-local BY CONSTRUCTION;
         # expert compute stays auto. Requires expert weights replicated over
         # the batch axes (ShardingRules does this when moe_group_by_batch).
-        from repro.parallel.compat import get_ambient_mesh, shard_map
-        mesh = get_ambient_mesh()
+        mesh = jax.sharding.get_abstract_mesh()
         axes = tuple(a for a in ("pod", "data")
-                     if mesh is not None and a in (mesh.axis_names or ()))
+                     if not mesh.empty and a in mesh.axis_names)
         if axes:
             from jax.sharding import PartitionSpec as P2
 
@@ -75,7 +74,7 @@ def apply_moe(p: dict, x: jax.Array, cfg: ModelConfig) -> Tuple[jax.Array, dict]
             # FULL-manual shard_map (all mesh axes): expert weights are
             # replicated (EP->DP for grouped mode), so the entire MoE layer
             # is collective-free and shard-local by construction.
-            fn = shard_map(
+            fn = jax.shard_map(
                 local_fn, mesh=mesh,
                 in_specs=(P2(axes, None, None),
                           jax.tree.map(lambda _: P2(), p)),

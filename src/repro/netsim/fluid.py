@@ -1200,10 +1200,13 @@ def simulate_batch(cfgs: Sequence[NetConfig], workload, scheme,
         validate_site_endpoints(tmpl, wlp)  # host-side: stalls fail early
     # fresh host-backed buffers: the jitted runner donates its batch inputs
     # (harmless on CPU where donation is skipped), so caller-held device
-    # arrays must never be passed through as-is
-    params = NetParams(*(jnp.asarray(np.asarray(v)) for v in params))
-    wlp = WorkloadParams(*(jnp.asarray(np.asarray(v)) for v in wlp))
+    # arrays must never be passed through as-is. One explicit device is
+    # honoured by committing the inputs to it (the launch runs there).
     devs = list(devices) if devices is not None else jax.devices()
+    put = (partial(jax.device_put, device=devs[0])
+           if devices is not None and len(devs) == 1 else jnp.asarray)
+    params = NetParams(*(put(np.asarray(v)) for v in params))
+    wlp = WorkloadParams(*(put(np.asarray(v)) for v in wlp))
     b = len(cfgs)
     pad = (-b) % len(devs) if len(devs) > 1 else 0
     if pad:
@@ -1253,6 +1256,16 @@ def _run_traced_batch_impl(cfg, params, wlp, scheme, steps, period_slots,
     return jax.vmap(one_scenario)(params, wlp)
 
 
+def _jit_traced_batch(donate_argnums=()):
+    """The batch runner jitted with its static arguments; ``donate_argnums``
+    ``(1, 2)`` donates the stacked (params, workload) inputs."""
+    return partial(jax.jit,
+                   static_argnames=("cfg", "scheme", "steps", "period_slots",
+                                    "delay_pad", "history_slots", "mode",
+                                    "decimate", "warm", "channel"),
+                   donate_argnums=donate_argnums)(_run_traced_batch_impl)
+
+
 @lru_cache(maxsize=1)
 def _jitted_traced_batch():
     """Build the jitted batch runner on FIRST use, not at import: the
@@ -1261,12 +1274,8 @@ def _jitted_traced_batch():
     stacked batch inputs are donated so giant-grid chunk launches reuse
     their buffers in place (XLA ignores donation on CPU and would warn
     about it, hence none there)."""
-    donate = () if jax.default_backend() == "cpu" else (1, 2)
-    return partial(jax.jit,
-                   static_argnames=("cfg", "scheme", "steps", "period_slots",
-                                    "delay_pad", "history_slots", "mode",
-                                    "decimate", "warm", "channel"),
-                   donate_argnums=donate)(_run_traced_batch_impl)
+    return _jit_traced_batch(() if jax.default_backend() == "cpu"
+                             else (1, 2))
 
 
 def _run_traced_batch(*args, **kwargs):

@@ -15,6 +15,8 @@ This module provides:
   * ``git_rev`` / ``memory_figures`` — the canonical helpers the benches
     re-export through ``benchmarks/record.py`` (src never imports
     benchmarks).
+  * ``configure_compile_cache`` — JAX's persistent compilation cache at
+    a fixed path, for entry points that run on a chip (never at import).
   * ``write_manifest`` / ``read_manifest`` — JSONL run manifests: one
     header record (git rev, plan sha256 fingerprint, backend, grid
     summary) followed by one record per launch (scheme, cell range,
@@ -52,6 +54,24 @@ def git_rev(cwd: Optional[str] = None) -> str:
         return rev if out.returncode == 0 and rev else "unknown"
     except Exception:
         return "unknown"
+
+
+def configure_compile_cache(root: str) -> str:
+    """Turn on JAX's persistent compilation cache and return its
+    directory: ``$JAX_COMPILATION_CACHE_DIR`` when set (JAX reads that
+    variable itself, so no other directory is set here), else
+    ``<root>/.jax_cache``. The path is part of what a later run has to find
+    again, so it is fixed, never built from a temporary name, a pid or the
+    time. Every compile is written (minimum compile time 0): the default
+    one-second floor would skip the faster netsim programs. Entry points
+    call this in ``main()``; importing ``repro`` never does."""
+    import jax
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = os.path.join(os.path.abspath(root), ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return path
 
 
 def memory_figures(compiled) -> dict:
@@ -95,9 +115,10 @@ def profiled_traced_batch(cfg, params, wlp, scheme, steps, period_slots,
     """Run the batched engine through an explicit lower → compile →
     execute pipeline, filling ``profile`` in place with:
 
-    ``compile_s`` / ``compile_cached`` / ``execute_s`` / ``backend`` and
-    the ``memory_figures`` of the executable. Returns the engine output
-    (same pytree as ``fluid._run_traced_batch``)."""
+    ``compile_s`` / ``compile_cached`` / ``execute_s`` / ``backend`` (the
+    platform the outputs live on, i.e. where the launch ran — not the
+    default backend) and the ``memory_figures`` of the executable. Returns
+    the engine output (same pytree as ``fluid._run_traced_batch``)."""
     import jax
     from repro.netsim import fluid
 
@@ -116,12 +137,13 @@ def profiled_traced_batch(cfg, params, wlp, scheme, steps, period_slots,
         _AOT_CACHE[key] = compiled
     profile["compile_s"] = time.perf_counter() - t0 if not cached else 0.0
     profile["compile_cached"] = cached
-    profile["backend"] = jax.default_backend()
     profile.update(memory_figures(compiled))
     t0 = time.perf_counter()
     out = compiled(params, wlp)
     out = jax.block_until_ready(out)
     profile["execute_s"] = time.perf_counter() - t0
+    leaf = jax.tree_util.tree_leaves(out)[0]
+    profile["backend"] = next(iter(leaf.devices())).platform
     return out
 
 

@@ -707,7 +707,8 @@ def _execute_plan(plan: Sequence[_Launch], cfgs, wlp: WorkloadParams,
         executed_recs = [m for m in manifest if not m.get("resumed")]
         header = {
             "fingerprint": fingerprint,
-            "backend": jax.default_backend(),
+            "backend": (devices[0].platform if devices is not None
+                        else jax.default_backend()),
             "n_devices": n_dev,
             "trace_mode": trace_mode,
             "decimate": int(decimate),

@@ -2,9 +2,6 @@ from repro.parallel.collectives import (
     hierarchical_grad_reduce, inter_pod_bytes_per_step,
     make_hierarchical_allreduce,
 )
-from repro.parallel.compat import (
-    axis_size, get_ambient_mesh, make_mesh, set_mesh, shard_map,
-)
 from repro.parallel.compression import (
     compress_with_feedback, compressed_psum, dequantize_int8, quantize_int8,
 )
@@ -14,5 +11,4 @@ __all__ = [
     "hierarchical_grad_reduce", "inter_pod_bytes_per_step",
     "make_hierarchical_allreduce", "compress_with_feedback", "compressed_psum",
     "dequantize_int8", "quantize_int8", "ShardingRules", "named",
-    "axis_size", "get_ambient_mesh", "make_mesh", "set_mesh", "shard_map",
 ]

@@ -9,10 +9,8 @@ The pattern that minimizes inter-DC bytes (DESIGN.md §6):
                                int8-compressed with error feedback)
     all-gather intra-pod      (ICI)
 
-Implemented with ``shard_map`` over the production mesh (through the
-version-compat shim in ``repro.parallel.compat`` — ``jax.shard_map`` on
-new JAX, ``jax.experimental.shard_map`` on 0.4.x). Used by the geo train
-step and unit-tested on a host-device mesh.
+Implemented with ``jax.shard_map`` over the production mesh. Used by the
+geo train step and unit-tested on a host-device mesh.
 """
 from __future__ import annotations
 
@@ -23,7 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.parallel.compat import axis_size, shard_map
 from repro.parallel.compression import compressed_psum
 
 
@@ -36,8 +33,8 @@ def hierarchical_grad_reduce(g: jax.Array, *, pod_axis: str = "pod",
     Equivalent to psum(g)/(n_pod*n_intra) but structured so only the
     scattered shard crosses the pod axis. Returns (g_mean, new_err).
     """
-    n_intra = axis_size(intra_axis)
-    n_pod = axis_size(pod_axis)
+    n_intra = jax.lax.axis_size(intra_axis)
+    n_pod = jax.lax.axis_size(pod_axis)
 
     # 1) reduce-scatter intra-pod along a padded leading dim
     flat = g.reshape(-1)
@@ -76,7 +73,7 @@ def make_hierarchical_allreduce(mesh: Mesh, *, compress: bool = False):
 
     pspec = P()  # grads replicated over pod/data in this demonstration path
 
-    @partial(shard_map, mesh=mesh, in_specs=(pspec, pspec),
+    @partial(jax.shard_map, mesh=mesh, in_specs=(pspec, pspec),
              out_specs=(pspec, pspec), check_vma=False)
     def _reduce_one(g, err):
         out, new_err = hierarchical_grad_reduce(

@@ -344,6 +344,58 @@ def test_manifest_roundtrip_and_obs_report(tmp_path):
     assert "deadbee" in text and "cafef00" in text  # both revs surfaced
 
 
+def test_sweep_manifest_names_the_device_it_ran_on(tmp_path):
+    """``manifest_path=`` on one explicit device: the launches are profiled
+    there, header and launch records name that device's platform, and the
+    rows equal the unprofiled sweep's."""
+    cfgs = [NetConfig(distance_km=d) for d in BATCH_DISTS]
+    wl = congestion_workload(**SEQ_WL_KW)
+    dev = jax.devices("cpu")[0]
+    path = str(tmp_path / "sweep.jsonl")
+    kw = dict(trace_mode="metrics", devices=[dev])
+    rows = sweep_grid(cfgs, wl, ("dcqcn", "matchrdma"), BATCH_HORIZON_US,
+                      manifest_path=path, **kw)
+    plain = sweep_grid(cfgs, wl, ("dcqcn", "matchrdma"), BATCH_HORIZON_US,
+                       **kw)
+    assert json.dumps(rows, sort_keys=True) == json.dumps(plain,
+                                                          sort_keys=True)
+    header, launches = read_manifest(path)
+    assert header["backend"] == dev.platform
+    assert [ln["scheme"] for ln in launches] == ["dcqcn", "matchrdma"]
+    for ln in launches:
+        assert ln["backend"] == dev.platform
+        assert ln["execute_s"] > 0.0 and not ln.get("oom_split")
+
+
+def test_configure_compile_cache(tmp_path, monkeypatch):
+    """A fixed ``<root>/.jax_cache`` unless ``$JAX_COMPILATION_CACHE_DIR``
+    is set (then no directory is set in code); every compile is written."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    from repro.netsim.obs.profile import configure_compile_cache
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs")
+    was = {k: getattr(jax.config, k) for k in keys}
+    try:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        own = str(tmp_path / ".jax_cache")
+        assert configure_compile_cache(str(tmp_path)) == own
+        assert jax.config.jax_compilation_cache_dir == own
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
+
+        jax.config.update("jax_compilation_cache_dir",
+                          was["jax_compilation_cache_dir"])
+        env = str(tmp_path / "from_env")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env)
+        assert configure_compile_cache(str(tmp_path)) == env
+        assert (jax.config.jax_compilation_cache_dir
+                == was["jax_compilation_cache_dir"])
+    finally:
+        for k, v in was.items():
+            jax.config.update(k, v)
+        cc.reset_cache()
+
+
 def test_timeline_export_valid_chrome_trace(tmp_path):
     cfg = _obs_cfg(**SEQ_CFG_KW)
     wl = congestion_workload(**HOT_WL_KW)

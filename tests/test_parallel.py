@@ -112,10 +112,9 @@ def test_error_feedback_unbiased_over_time():
 
 # ------------------------- multi-device (subprocess) -------------------------
 
-# The subprocess scripts drive the multi-device code through the
-# version-compat shims (repro.parallel.compat): jax.shard_map / set_mesh /
-# AxisType meshes on new JAX, jax.experimental.shard_map + the Mesh context
-# on 0.4.x — they RUN (not skip) on every supported install.
+# The subprocess scripts drive the multi-device code on 8 virtual CPU
+# devices: an all-Auto mesh (repro.launch.mesh.make_mesh) entered with
+# jax.set_mesh.
 
 _SUBPROC = textwrap.dedent("""
     import os, sys
@@ -123,12 +122,12 @@ _SUBPROC = textwrap.dedent("""
     sys.path.insert(0, "src")
     import jax, jax.numpy as jnp
     from repro.parallel import make_hierarchical_allreduce
-    from repro.parallel.compat import make_mesh, set_mesh
+    from repro.launch.mesh import make_mesh
     mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     g = {"a": jnp.arange(37, dtype=jnp.float32) * 0.1,
          "b": jnp.ones((5, 3), jnp.bfloat16)}
     errs = jax.tree.map(lambda x: jnp.zeros_like(x, dtype=jnp.float32), g)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         out, _ = jax.jit(make_hierarchical_allreduce(mesh))(g, errs)
         assert float(jnp.abs(out["a"] - g["a"]).max()) < 1e-6
         outc, ne = jax.jit(make_hierarchical_allreduce(mesh, compress=True))(g, errs)
@@ -152,7 +151,7 @@ _SUBPROC_MOE = textwrap.dedent("""
     import jax, jax.numpy as jnp
     from repro.config import get_model_config
     from repro.models.moe import apply_moe, init_moe
-    from repro.parallel.compat import make_mesh, set_mesh
+    from repro.launch.mesh import make_mesh
     cfg = dataclasses.replace(
         get_model_config("phi3.5-moe-42b-a6.6b", smoke=True),
         act_dtype="float32", param_dtype="float32", moe_capacity_factor=8.0)
@@ -161,7 +160,7 @@ _SUBPROC_MOE = textwrap.dedent("""
     x = jax.random.normal(jax.random.PRNGKey(1), (4, 32, cfg.d_model))
     y_flat, _ = apply_moe(p, x, cfg)                 # ungrouped reference
     cfg_g = dataclasses.replace(cfg, moe_group_by_batch=True)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         y_grp, aux = jax.jit(lambda x, p: apply_moe(p, x, cfg_g))(x, p)
     err = float(jnp.abs(y_flat - y_grp).max())
     assert err < 1e-5, err
@@ -178,16 +177,18 @@ def test_grouped_moe_shardmap_8dev():
 
 
 def test_compat_shard_map_single_device():
-    """The compat shim itself, in-process on one device: shard_map over a
-    trivial mesh reduces correctly whichever JAX generation is installed."""
-    from repro.parallel.compat import get_ambient_mesh, set_mesh, shard_map
-    mesh = jax.make_mesh((1,), ("x",))
-    f = shard_map(lambda v: jax.lax.psum(v, "x"), mesh=mesh,
-                  in_specs=P("x"), out_specs=P(None))
+    """The mesh calls the multi-device code makes, in-process on one
+    device: jax.shard_map over a trivial mesh reduces correctly, and
+    jax.set_mesh is what jax.sharding.get_abstract_mesh reads back (the
+    ambient mesh the grouped-MoE path keys on)."""
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1,), ("x",))
+    f = jax.shard_map(lambda v: jax.lax.psum(v, "x"), mesh=mesh,
+                      in_specs=P("x"), out_specs=P(None))
     out = f(jnp.arange(4, dtype=jnp.float32))
     np.testing.assert_allclose(np.asarray(out), np.arange(4, dtype=np.float32))
-    assert get_ambient_mesh() is None
-    with set_mesh(mesh):
-        amb = get_ambient_mesh()
-        assert amb is not None and "x" in amb.axis_names
-    assert get_ambient_mesh() is None
+    assert jax.sharding.get_abstract_mesh().empty
+    with jax.set_mesh(mesh):
+        amb = jax.sharding.get_abstract_mesh()
+        assert not amb.empty and "x" in amb.axis_names
+    assert jax.sharding.get_abstract_mesh().empty
