@@ -1,0 +1,15 @@
+"""Device self time per scan step of the traced launch in the
+``netsim.rings`` scope: the delay-ring reads and writes: delayed inputs,
+the pipe and pause- line writes, the return paths (2, 5, 6, 10). From
+the profiler trace and the scope map of the launch's HLO
+(``bench/phases.py``). Layer: the scan step's phases
+(``netsim/fluid.py`` ``make_step_fn``)."""
+from bench import phases
+
+LAYER = "scan step phases"
+UNIT = "us"
+MOVES = "scenario_steps_per_s"
+
+
+def read(obs):
+    return phases.phase_us(obs, "rings")

@@ -102,6 +102,7 @@ from repro.core.matchrdma import default_history_slots
 from repro.netsim.channel import (
     ChannelInputs, ChannelModel, get_channel_model, scenario_key,
 )
+from repro.netsim.obs.profile import span
 from repro.netsim.queues import drain_proportional, ecn_mark_prob, pfc_hysteresis
 from repro.netsim.soft import lerp, reset_gate, soft_gt, soft_pos, ste
 from repro.netsim.schemes import SCHEMES, get_scheme  # noqa: F401 (re-export)
@@ -515,158 +516,178 @@ def make_step_fn(cfg: NetConfig, wl: WorkloadParams, scheme,
         fail_lo, fail_hi = fw[..., 0], fw[..., 1]      # [L, W]
 
     def step(state: SimState, t: jax.Array):
+        # Each phase below runs under a ``netsim.<phase>`` named scope and
+        # each scheme hook under ``hook.<method>`` (docs/observability.md):
+        # scopes only name the HLO ops' metadata, so a device trace can be
+        # attributed per phase; the values and the jaxpr are unchanged.
         t_us = t.astype(jnp.float32) * dt_us
         ridx = jnp.mod(t, d_steps)
 
-        # -------------------------------------------- 0. failure live-mask
-        # A link is DOWN inside any of its (down_at, up_at) windows
-        # (strict upper bound, so padding (0, 0) windows never fire).
-        # Schemes see the mask through ``SchemeCtx.link_live`` and
-        # re-spray their routing weights over the survivors; at an
-        # all-up step every where() below selects the ORIGINAL tensor,
-        # keeping the program bit-identical to a schedule-free run.
-        if has_fail:
-            link_down = jnp.any((t_us >= fail_lo) & (t_us < fail_hi),
-                                axis=-1)                           # [L]
-            link_live = 1.0 - link_down.astype(jnp.float32)        # [L]
-            hctx = ctx._replace(link_live=link_live)
-        else:
-            hctx = ctx
+        with jax.named_scope("netsim.flow"):
+            # ---------------------------------------- 0. failure live-mask
+            # A link is DOWN inside any of its (down_at, up_at) windows
+            # (strict upper bound, so padding (0, 0) windows never fire).
+            # Schemes see the mask through ``SchemeCtx.link_live`` and
+            # re-spray their routing weights over the survivors; at an
+            # all-up step every where() below selects the ORIGINAL tensor,
+            # keeping the program bit-identical to a schedule-free run.
+            if has_fail:
+                link_down = jnp.any((t_us >= fail_lo) & (t_us < fail_hi),
+                                    axis=-1)                       # [L]
+                link_live = 1.0 - link_down.astype(jnp.float32)    # [L]
+                hctx = ctx._replace(link_live=link_live)
+            else:
+                hctx = ctx
 
-        # ------------------------------------------------ 1. flow phase
-        if soft is None:
-            started = (t_us >= start_us).astype(jnp.float32)
-            in_period = jnp.where(
-                period_us > 0,
-                (jnp.mod(jnp.maximum(t_us - start_us, 0.0),
-                         jnp.maximum(period_us, 1.0))
-                 < duty * period_us).astype(jnp.float32),
-                1.0)
-            not_done = (state.delivered < total_bytes).astype(jnp.float32)
-        else:
-            started = soft_gt(t_us, start_us, soft, dt_us)
-            phase = jnp.mod(jnp.maximum(t_us - start_us, 0.0),
-                            jnp.maximum(period_us, 1.0))
-            gate = soft_gt(duty * period_us, phase, soft, dt_us)
-            in_period = lerp(soft_pos(period_us, soft, dt_us), gate, 1.0)
-            # STE on the activity gate: the forward pass keeps the exact
-            # live-mask (consistent with the hard completion latch below);
-            # the backward pass sees the tempered gate
-            not_done = ste(
-                (state.delivered < total_bytes).astype(jnp.float32),
-                soft_gt(total_bytes, state.delivered, soft,
-                        jnp.maximum(1e-3 * total_bytes, MTU)))
-        active = started * in_period * not_done * active_mask
+            # -------------------------------------------- 1. flow phase
+            if soft is None:
+                started = (t_us >= start_us).astype(jnp.float32)
+                in_period = jnp.where(
+                    period_us > 0,
+                    (jnp.mod(jnp.maximum(t_us - start_us, 0.0),
+                             jnp.maximum(period_us, 1.0))
+                     < duty * period_us).astype(jnp.float32),
+                    1.0)
+                not_done = (state.delivered
+                            < total_bytes).astype(jnp.float32)
+            else:
+                started = soft_gt(t_us, start_us, soft, dt_us)
+                phase = jnp.mod(jnp.maximum(t_us - start_us, 0.0),
+                                jnp.maximum(period_us, 1.0))
+                gate = soft_gt(duty * period_us, phase, soft, dt_us)
+                in_period = lerp(soft_pos(period_us, soft, dt_us), gate, 1.0)
+                # STE on the activity gate: the forward pass keeps the
+                # exact live-mask (consistent with the hard completion
+                # latch below); the backward pass sees the tempered gate
+                not_done = ste(
+                    (state.delivered < total_bytes).astype(jnp.float32),
+                    soft_gt(total_bytes, state.delivered, soft,
+                            jnp.maximum(1e-3 * total_bytes, MTU)))
+            active = started * in_period * not_done * active_mask
 
         # ------------------------------------------------ 2. delayed inputs
-        ack_arr = state.ack_line[ridx]
-        cnp_arr = state.cnp_line[ridx]
-        if multi:
-            # each link's ring row wraps at ITS OWN traced delay: row l of
-            # the padded ring holds what link l launched d_l steps ago
-            lidx = jnp.mod(t, link_d_steps)            # [L]
-            pause_sig = state.pause_line[lidx, link_ids]        # [L]
-            pipe_out = state.pipe[lidx, link_ids]               # [L, F]
-        else:
-            pause_sig = state.pause_line[ridx]
-            pipe_out = state.pipe[ridx]
+        with jax.named_scope("netsim.rings"):
+            ack_arr = state.ack_line[ridx]
+            cnp_arr = state.cnp_line[ridx]
+            if multi:
+                # each link's ring row wraps at ITS OWN traced delay: row l
+                # of the padded ring holds what link l launched d_l steps ago
+                lidx = jnp.mod(t, link_d_steps)            # [L]
+                pause_sig = state.pause_line[lidx, link_ids]        # [L]
+                pipe_out = state.pipe[lidx, link_ids]               # [L, F]
+            else:
+                pause_sig = state.pause_line[ridx]
+                pipe_out = state.pipe[ridx]
 
-        # ------------------------------------------------ 2b. channel hook
-        # The single hook point of the channel subsystem: what leaves the
-        # pipe is impaired BEFORE the destination OTN sees it, and the
-        # source-OTN line capacity may be dimmed (OTN flap). Lost bytes
-        # ride the loss-notification ring back to the source (delay D).
-        # At L > 1 the model is vmapped over the link axis — each parallel
-        # path carries its own impairment process (independent keys, own
-        # flap phase / loss chain / jitter buffer).
-        if soft is None:
-            paused_src = pause_sig > 0.5               # delayed dst PFC
-            if multi:
-                cap_link = jnp.where(paused_src, 0.0,
-                                     link_caps * dt_s)           # [L]
-                if has_fail:
-                    cap_link = jnp.where(link_down, 0.0, cap_link)
-                cap_src = jnp.sum(cap_link)
+        # The source line's capacity this step, from the delayed dst PFC
+        # (and the failure mask): what the source OTN may release.
+        with jax.named_scope("netsim.src_otn"):
+            if soft is None:
+                paused_src = pause_sig > 0.5               # delayed dst PFC
+                if multi:
+                    cap_link = jnp.where(paused_src, 0.0,
+                                         link_caps * dt_s)       # [L]
+                    if has_fail:
+                        cap_link = jnp.where(link_down, 0.0, cap_link)
+                    cap_src = jnp.sum(cap_link)
+                else:
+                    cap_src = jnp.where(paused_src, 0.0, c_otn * dt_s)
+                    if has_fail:
+                        cap_src = jnp.where(link_down[0], 0.0, cap_src)
             else:
-                cap_src = jnp.where(paused_src, 0.0, c_otn * dt_s)
-                if has_fail:
-                    cap_src = jnp.where(link_down[0], 0.0, cap_src)
-        else:
-            # the delayed pause signal already lives in [0, 1] in soft
-            # mode (soft_hysteresis); re-temper it around the midpoint and
-            # scale the capacity instead of zeroing it. The failure mask
-            # is schedule-structure (knob-independent), so the hard 0/1
-            # multiplier stays.
-            w_pause = soft_gt(pause_sig, 0.5, soft, 0.25)
-            if multi:
-                cap_link = (1.0 - w_pause) * link_caps * dt_s    # [L]
-                if has_fail:
-                    cap_link = cap_link * link_live
-                cap_src = jnp.sum(cap_link)
-            else:
-                cap_src = (1.0 - w_pause) * c_otn * dt_s
-                if has_fail:
-                    cap_src = cap_src * link_live[0]
-        retx_arr = state.retx_line[ridx] if repair else zero_f
-        if impaired:
-            if multi:
-                step_key = jax.random.fold_in(chan_key0, t)
-                keys = jax.vmap(
-                    lambda l: jax.random.fold_in(step_key, l))(link_ids)
-                eff = jax.vmap(
-                    lambda c, k, po, cs: channel.apply_impairments(
-                        ctx, c, ChannelInputs(t=t, key=k, pipe_out=po,
-                                              cap_src=cs)))(
-                    state.chan, keys, pipe_out, cap_link)
-                pipe_arrivals, chan_new = eff.arrivals, eff.chan  # [L, F]
-                lost = jnp.sum(eff.lost, axis=0)                  # [F]
-                cap_link = eff.cap_src                            # [L]
-                cap_src = jnp.sum(cap_link)
-            else:
-                eff = channel.apply_impairments(ctx, state.chan, ChannelInputs(
-                    t=t, key=jax.random.fold_in(chan_key0, t),
-                    pipe_out=pipe_out, cap_src=cap_src))
-                pipe_arrivals, lost = eff.arrivals, eff.lost
-                cap_src, chan_new = eff.cap_src, eff.chan
-        else:
-            pipe_arrivals, lost, chan_new = pipe_out, zero_f, None
-        # -------------------------------------------- 2c. outage dump
-        # Bytes reaching the far end of a DEAD link are lost there and
-        # ride the loss-notification ring back: conservation holds
-        # through the outage and the data re-enters the source queue to
-        # be re-sprayed over the surviving links. (Bytes in flight when
-        # a link dies keep transiting the ring; they are dumped at exit
-        # time while the link stays down, delivered if it came back.)
-        if has_fail:
-            if multi:
-                deadc = link_down[:, None]                       # [L, 1]
-                fail_lost = jnp.sum(
-                    jnp.where(deadc, pipe_arrivals, 0.0), axis=0)  # [F]
-                pipe_arrivals = jnp.where(deadc, 0.0, pipe_arrivals)
-            else:
-                fail_lost = jnp.where(link_down[0], pipe_arrivals, zero_f)
-                pipe_arrivals = jnp.where(link_down[0], zero_f,
-                                          pipe_arrivals)
-            lost = jnp.where(fail_lost > 0.0, lost + fail_lost, lost)
+                # the delayed pause signal already lives in [0, 1] in soft
+                # mode (soft_hysteresis); re-temper it around the midpoint
+                # and scale the capacity instead of zeroing it. The failure
+                # mask is schedule-structure (knob-independent), so the
+                # hard 0/1 multiplier stays.
+                w_pause = soft_gt(pause_sig, 0.5, soft, 0.25)
+                if multi:
+                    cap_link = (1.0 - w_pause) * link_caps * dt_s  # [L]
+                    if has_fail:
+                        cap_link = cap_link * link_live
+                    cap_src = jnp.sum(cap_link)
+                else:
+                    cap_src = (1.0 - w_pause) * c_otn * dt_s
+                    if has_fail:
+                        cap_src = cap_src * link_live[0]
+        with jax.named_scope("netsim.rings"):
+            retx_arr = state.retx_line[ridx] if repair else zero_f
 
-        # ------------------------------------------------ 3. ACK accounting
-        acked_inter = scheme.ack_view(hctx, state, ack_arr)
-        acked = jnp.where(is_inter > 0, acked_inter,
-                          state.delivered)             # intra: ~µs loop
-        acked = jnp.minimum(acked, state.sent)
+        with jax.named_scope("netsim.channel"):
+            # ---------------------------------------- 2b. channel hook
+            # The single hook point of the channel subsystem: what leaves
+            # the pipe is impaired BEFORE the destination OTN sees it, and
+            # the source-OTN line capacity may be dimmed (OTN flap). Lost
+            # bytes ride the loss-notification ring back to the source
+            # (delay D). At L > 1 the model is vmapped over the link axis —
+            # each parallel path carries its own impairment process
+            # (independent keys, own flap phase / loss chain / jitter
+            # buffer).
+            if impaired:
+                if multi:
+                    step_key = jax.random.fold_in(chan_key0, t)
+                    keys = jax.vmap(
+                        lambda l: jax.random.fold_in(step_key, l))(link_ids)
+                    eff = jax.vmap(
+                        lambda c, k, po, cs: channel.apply_impairments(
+                            ctx, c, ChannelInputs(t=t, key=k, pipe_out=po,
+                                                  cap_src=cs)))(
+                        state.chan, keys, pipe_out, cap_link)
+                    pipe_arrivals, chan_new = eff.arrivals, eff.chan
+                    lost = jnp.sum(eff.lost, axis=0)                  # [F]
+                    cap_link = eff.cap_src                            # [L]
+                    cap_src = jnp.sum(cap_link)
+                else:
+                    eff = channel.apply_impairments(
+                        ctx, state.chan, ChannelInputs(
+                            t=t, key=jax.random.fold_in(chan_key0, t),
+                            pipe_out=pipe_out, cap_src=cap_src))
+                    pipe_arrivals, lost = eff.arrivals, eff.lost
+                    cap_src, chan_new = eff.cap_src, eff.chan
+            else:
+                pipe_arrivals, lost, chan_new = pipe_out, zero_f, None
+            # ---------------------------------------- 2c. outage dump
+            # Bytes reaching the far end of a DEAD link are lost there and
+            # ride the loss-notification ring back: conservation holds
+            # through the outage and the data re-enters the source queue
+            # to be re-sprayed over the surviving links. (Bytes in flight
+            # when a link dies keep transiting the ring; they are dumped at
+            # exit time while the link stays down, delivered if it came
+            # back.)
+            if has_fail:
+                if multi:
+                    deadc = link_down[:, None]                   # [L, 1]
+                    fail_lost = jnp.sum(
+                        jnp.where(deadc, pipe_arrivals, 0.0), axis=0)  # [F]
+                    pipe_arrivals = jnp.where(deadc, 0.0, pipe_arrivals)
+                else:
+                    fail_lost = jnp.where(link_down[0], pipe_arrivals,
+                                          zero_f)
+                    pipe_arrivals = jnp.where(link_down[0], zero_f,
+                                              pipe_arrivals)
+                lost = jnp.where(fail_lost > 0.0, lost + fail_lost, lost)
 
-        # ------------------------------------------------ 4. sender rates
-        win_avail = jnp.maximum(window - (state.sent - acked), 0.0)
-        base_rate = jnp.minimum(win_avail / dt_s, nic)
-        rate = scheme.sender_rate(hctx, state, base_rate)
-        # src-OTN -> sender PFC (1 step, from last-step queue)
-        if soft is None:
-            src_nic_pause = (jnp.sum(state.q_src)
-                             > xoff_otn).astype(jnp.float32)
-        else:
-            src_nic_pause = soft_gt(jnp.sum(state.q_src), xoff_otn, soft,
-                                    0.05 * xoff_otn + 1.0)
-        rate = rate * jnp.where(is_inter > 0, 1.0 - src_nic_pause, 1.0)
+        with jax.named_scope("netsim.ack_rate"):
+            # -------------------------------------------- 3. ACK accounting
+            with jax.named_scope("hook.ack_view"):
+                acked_inter = scheme.ack_view(hctx, state, ack_arr)
+            acked = jnp.where(is_inter > 0, acked_inter,
+                              state.delivered)         # intra: ~µs loop
+            acked = jnp.minimum(acked, state.sent)
+
+            # -------------------------------------------- 4. sender rates
+            win_avail = jnp.maximum(window - (state.sent - acked), 0.0)
+            base_rate = jnp.minimum(win_avail / dt_s, nic)
+            with jax.named_scope("hook.sender_rate"):
+                rate = scheme.sender_rate(hctx, state, base_rate)
+            # src-OTN -> sender PFC (1 step, from last-step queue)
+            if soft is None:
+                src_nic_pause = (jnp.sum(state.q_src)
+                                 > xoff_otn).astype(jnp.float32)
+            else:
+                src_nic_pause = soft_gt(jnp.sum(state.q_src), xoff_otn,
+                                        soft, 0.05 * xoff_otn + 1.0)
+            rate = rate * jnp.where(is_inter > 0, 1.0 - src_nic_pause, 1.0)
         # -------------------------------------------- 4b. loss repair
         # Lost bytes whose notification has arrived are retransmitted with
         # priority: the scheme grants a repair rate (retx_rate) and the
@@ -679,148 +700,176 @@ def make_step_fn(cfg: NetConfig, wl: WorkloadParams, scheme,
         # pre-channel program's, which the zero-impairment identity test
         # pins bit-for-bit against the goldens.
         if repair:
-            backlog_avail = state.retx_backlog + retx_arr
-            retx_bps = jnp.maximum(scheme.retx_rate(hctx, state, rate), 0.0)
-            retx_send = (jnp.minimum(jnp.minimum(backlog_avail,
-                                                 retx_bps * dt_s),
-                                     nic * dt_s)
-                         * is_inter * (1.0 - src_nic_pause))
-            rate = jnp.where(retx_send > 0.0,
-                             jnp.maximum(rate - retx_send / dt_s, 0.0),
-                             rate)
-            retx_backlog = backlog_avail - retx_send
+            with jax.named_scope("netsim.channel"):
+                backlog_avail = state.retx_backlog + retx_arr
+                with jax.named_scope("hook.retx_rate"):
+                    retx_bps = jnp.maximum(
+                        scheme.retx_rate(hctx, state, rate), 0.0)
+                retx_send = (jnp.minimum(jnp.minimum(backlog_avail,
+                                                     retx_bps * dt_s),
+                                         nic * dt_s)
+                             * is_inter * (1.0 - src_nic_pause))
+                rate = jnp.where(retx_send > 0.0,
+                                 jnp.maximum(rate - retx_send / dt_s, 0.0),
+                                 rate)
+                retx_backlog = backlog_avail - retx_send
         else:
             retx_send, retx_backlog = zero_f, zero_f
-        send = rate * active * dt_s                    # bytes this step
-        sent = state.sent + send
+        with jax.named_scope("netsim.ack_rate"):
+            send = rate * active * dt_s                # bytes this step
+            sent = state.sent + send
 
         # ------------------------------------------------ 5. source OTN
-        arrivals_src = send * is_inter
-        if repair:
-            # where(): at retx_send == 0 the select returns the original
-            # arrivals tensor (see the send select above)
-            arrivals_src = jnp.where(retx_send > 0.0,
-                                     arrivals_src + retx_send, arrivals_src)
-        q_src, drained_src = scheme.src_otn_release(hctx, state, arrivals_src,
-                                                    cap_src, active)
-        if multi:
-            # spray the scheme's aggregate release across the parallel
-            # links: per-flow weights (workload routing matrix, reweighted
-            # by the scheme's route_weights hook), masked by links with
-            # capacity this step, then clipped per link. Bytes a saturated
-            # link cannot take spill back into the source-OTN queue — an
-            # equal-weight spray over unequal paths therefore bottlenecks
-            # on its slowest link, which is exactly the imbalance
-            # token-gated spraying (rdmacell) adapts away.
-            w = jnp.maximum(scheme.route_weights(hctx, state, route), 0.0)
-            if soft is None:
-                w = w * (cap_link > 0.0)[None, :]                 # [F, L]
+        with jax.named_scope("netsim.src_otn"):
+            arrivals_src = send * is_inter
+            if repair:
+                # where(): at retx_send == 0 the select returns the
+                # original arrivals tensor (see the send select above)
+                arrivals_src = jnp.where(retx_send > 0.0,
+                                         arrivals_src + retx_send,
+                                         arrivals_src)
+            with jax.named_scope("hook.src_otn_release"):
+                q_src, drained_src = scheme.src_otn_release(
+                    hctx, state, arrivals_src, cap_src, active)
+            if multi:
+                # spray the scheme's aggregate release across the parallel
+                # links: per-flow weights (workload routing matrix,
+                # reweighted by the scheme's route_weights hook), masked by
+                # links with capacity this step, then clipped per link.
+                # Bytes a saturated link cannot take spill back into the
+                # source-OTN queue — an equal-weight spray over unequal
+                # paths therefore bottlenecks on its slowest link, which is
+                # exactly the imbalance token-gated spraying (rdmacell)
+                # adapts away.
+                with jax.named_scope("hook.route_weights"):
+                    w = jnp.maximum(
+                        scheme.route_weights(hctx, state, route), 0.0)
+                if soft is None:
+                    w = w * (cap_link > 0.0)[None, :]             # [F, L]
+                else:
+                    # soft zero-cap mask: exactly 0 at cap 0 (soft_pos), so
+                    # a fully paused/flapped link still attracts no spray
+                    w = w * soft_pos(cap_link, soft, MTU)[None, :]
+                row = jnp.sum(w, axis=1, keepdims=True)
+                share = w / jnp.maximum(row, 1e-9)                # [F, L]
+                want = drained_src[:, None] * share               # [F, L]
+                link_want = jnp.sum(want, axis=0)                 # [L]
+                scale = jnp.minimum(
+                    1.0, cap_link / jnp.maximum(link_want, 1e-9))
+                sent_link = (want * scale[None, :]).T             # [L, F]
+                spilled = drained_src - jnp.sum(sent_link, axis=0)
+                q_src = q_src + spilled
+                with jax.named_scope("netsim.rings"):
+                    pipe = state.pipe.at[lidx, link_ids].set(sent_link)
+                inflight = (state.inflight + jnp.sum(sent_link, axis=0)
+                            - jnp.sum(pipe_out, axis=0))
+                link_tx = jnp.sum(sent_link, axis=1)              # [L]
             else:
-                # soft zero-cap mask: exactly 0 at cap 0 (soft_pos), so a
-                # fully paused/flapped link still attracts no spray
-                w = w * soft_pos(cap_link, soft, MTU)[None, :]
-            row = jnp.sum(w, axis=1, keepdims=True)
-            share = w / jnp.maximum(row, 1e-9)                    # [F, L]
-            want = drained_src[:, None] * share                   # [F, L]
-            link_want = jnp.sum(want, axis=0)                     # [L]
-            scale = jnp.minimum(
-                1.0, cap_link / jnp.maximum(link_want, 1e-9))
-            sent_link = (want * scale[None, :]).T                 # [L, F]
-            spilled = drained_src - jnp.sum(sent_link, axis=0)
-            q_src = q_src + spilled
-            pipe = state.pipe.at[lidx, link_ids].set(sent_link)
-            inflight = (state.inflight + jnp.sum(sent_link, axis=0)
-                        - jnp.sum(pipe_out, axis=0))
-            link_tx = jnp.sum(sent_link, axis=1)                  # [L]
-        else:
-            pipe = state.pipe.at[ridx].set(drained_src)  # arrives at t + D
-            inflight = state.inflight + drained_src - pipe_out
+                with jax.named_scope("netsim.rings"):
+                    # arrives at t + D
+                    pipe = state.pipe.at[ridx].set(drained_src)
+                inflight = state.inflight + drained_src - pipe_out
 
-        # ------------------------------------------------ 6. destination OTN
-        if soft is None:
-            leaf_pfc = (jnp.sum(state.q_leaf) > xoff).astype(jnp.float32)
-        else:
-            leaf_pfc = soft_gt(jnp.sum(state.q_leaf), xoff, soft,
-                               0.05 * xoff + 1.0)
-        cap_dst = c_leaf * dt_s * (1.0 - leaf_pfc)
-        q_dst, drained_dst = drain_proportional(state.q_dst, pipe_arrivals,
-                                                cap_dst)
-        egress_bytes = jnp.sum(drained_dst)
-        q_dst_tot = jnp.sum(q_dst)
-        if multi:
-            # per-link backlog -> per-link PFC toward that link's source
-            # line; each pause rides back at the LINK's own delay
-            q_dst_link = jnp.sum(q_dst, axis=1)                   # [L]
-            pause_dst = pfc_hysteresis(state.pause_dst, q_dst_link,
-                                       xoff_link, xon_link,
-                                       soft=soft)                 # [L]
-            pause_line = state.pause_line.at[lidx, link_ids].set(pause_dst)
-            drained_dst_f = jnp.sum(drained_dst, axis=0)          # [F]
-        else:
-            pause_dst = pfc_hysteresis(state.pause_dst, q_dst_tot, xoff_otn,
-                                       xon_otn, soft=soft)
-            pause_line = state.pause_line.at[ridx].set(pause_dst)
-            drained_dst_f = drained_dst
+        with jax.named_scope("netsim.dst_queues"):
+            # -------------------------------------------- 6. destination OTN
+            if soft is None:
+                leaf_pfc = (jnp.sum(state.q_leaf)
+                            > xoff).astype(jnp.float32)
+            else:
+                leaf_pfc = soft_gt(jnp.sum(state.q_leaf), xoff, soft,
+                                   0.05 * xoff + 1.0)
+            cap_dst = c_leaf * dt_s * (1.0 - leaf_pfc)
+            q_dst, drained_dst = drain_proportional(state.q_dst,
+                                                    pipe_arrivals, cap_dst)
+            egress_bytes = jnp.sum(drained_dst)
+            q_dst_tot = jnp.sum(q_dst)
+            if multi:
+                # per-link backlog -> per-link PFC toward that link's source
+                # line; each pause rides back at the LINK's own delay
+                q_dst_link = jnp.sum(q_dst, axis=1)               # [L]
+                pause_dst = pfc_hysteresis(state.pause_dst, q_dst_link,
+                                           xoff_link, xon_link,
+                                           soft=soft)             # [L]
+                with jax.named_scope("netsim.rings"):
+                    pause_line = state.pause_line.at[lidx, link_ids].set(
+                        pause_dst)
+                drained_dst_f = jnp.sum(drained_dst, axis=0)      # [F]
+            else:
+                pause_dst = pfc_hysteresis(state.pause_dst, q_dst_tot,
+                                           xoff_otn, xon_otn, soft=soft)
+                with jax.named_scope("netsim.rings"):
+                    pause_line = state.pause_line.at[ridx].set(pause_dst)
+                drained_dst_f = drained_dst
 
-        # ------------------------------------------------ 7. destination leaf
-        arrivals_leaf = drained_dst_f + send * is_intra
-        mark_p = ecn_mark_prob(jnp.sum(state.q_leaf), cfg, params=params,
-                               soft=soft)
-        q_leaf, drained_leaf = drain_proportional(state.q_leaf, arrivals_leaf,
-                                                  c_leaf * dt_s)
-        delivered = state.delivered + drained_leaf
-        marked_acc = state.marked_acc + drained_leaf * mark_p
+            # -------------------------------------------- 7. destination leaf
+            arrivals_leaf = drained_dst_f + send * is_intra
+            mark_p = ecn_mark_prob(jnp.sum(state.q_leaf), cfg, params=params,
+                                   soft=soft)
+            q_leaf, drained_leaf = drain_proportional(state.q_leaf,
+                                                      arrivals_leaf,
+                                                      c_leaf * dt_s)
+            delivered = state.delivered + drained_leaf
+            marked_acc = state.marked_acc + drained_leaf * mark_p
 
-        # ------------------------------------------------ 8. CNP generation
-        cnp_timer = state.cnp_timer + dt_us
-        if soft is None:
-            want = marked_acc >= MTU
-            emit = want & (cnp_timer >= cfg.cnp_interval_us)
-            cnp_out = emit.astype(jnp.float32)
-            cnp_timer = jnp.where(emit, 0.0, cnp_timer)
-            marked_acc = jnp.where(emit, 0.0, marked_acc)
-        else:
-            # fractional CNPs: downstream consumers (slot classifier, CC
-            # cut gate) already read them through tempered gates at the
-            # 0.5 midpoint
-            cnp_out = (soft_gt(marked_acc, MTU, soft, 0.1 * MTU)
-                       * soft_gt(cnp_timer, cfg.cnp_interval_us, soft,
-                                 dt_us))
-            # self-referential resets take the DETACHED gate (soft.reset_gate
-            # docstring); cnp_out itself keeps full gradients downstream
-            cnp_timer = lerp(reset_gate(cnp_out), 0.0, cnp_timer)
-            marked_acc = lerp(reset_gate(cnp_out), 0.0, marked_acc)
+            # -------------------------------------------- 8. CNP generation
+            cnp_timer = state.cnp_timer + dt_us
+            if soft is None:
+                want = marked_acc >= MTU
+                emit = want & (cnp_timer >= cfg.cnp_interval_us)
+                cnp_out = emit.astype(jnp.float32)
+                cnp_timer = jnp.where(emit, 0.0, cnp_timer)
+                marked_acc = jnp.where(emit, 0.0, marked_acc)
+            else:
+                # fractional CNPs: downstream consumers (slot classifier,
+                # CC cut gate) already read them through tempered gates at
+                # the 0.5 midpoint
+                cnp_out = (soft_gt(marked_acc, MTU, soft, 0.1 * MTU)
+                           * soft_gt(cnp_timer, cfg.cnp_interval_us, soft,
+                                     dt_us))
+                # self-referential resets take the DETACHED gate
+                # (soft.reset_gate docstring); cnp_out itself keeps full
+                # gradients downstream
+                cnp_timer = lerp(reset_gate(cnp_out), 0.0, cnp_timer)
+                marked_acc = lerp(reset_gate(cnp_out), 0.0, marked_acc)
 
         # ------------------------------------------------ 9. scheme feedback
         # (CNP routing, pseudo-ACK ledger, proxy brake, slot/budget/channel)
-        fb = scheme.feedback(hctx, state, SchemeSignals(
-            t=t, active=active, sent=sent, cnp_out=cnp_out, cnp_arr=cnp_arr,
-            egress_bytes=egress_bytes, q_dst_tot=q_dst_tot, q_leaf=q_leaf,
-            leaf_pfc=leaf_pfc, retx_arr=retx_arr, retx_backlog=retx_backlog,
-            link_sent=sent_link if multi else None,
-            link_arrivals=pipe_arrivals if multi else None,
-            link_want=link_want if multi else None,
-            link_cap=cap_link if multi else None))
+        with jax.named_scope("netsim.feedback"), \
+                jax.named_scope("hook.feedback"):
+            fb = scheme.feedback(hctx, state, SchemeSignals(
+                t=t, active=active, sent=sent, cnp_out=cnp_out,
+                cnp_arr=cnp_arr, egress_bytes=egress_bytes,
+                q_dst_tot=q_dst_tot, q_leaf=q_leaf, leaf_pfc=leaf_pfc,
+                retx_arr=retx_arr, retx_backlog=retx_backlog,
+                link_sent=sent_link if multi else None,
+                link_arrivals=pipe_arrivals if multi else None,
+                link_want=link_want if multi else None,
+                link_cap=cap_link if multi else None))
 
         # ------------------------------------------------ 10. return paths
-        ack_line = state.ack_line.at[ridx].set(drained_leaf * is_inter)
-        cnp_line = state.cnp_line.at[ridx].set(fb.cnp_wire)
+        with jax.named_scope("netsim.rings"):
+            ack_line = state.ack_line.at[ridx].set(drained_leaf * is_inter)
+            cnp_line = state.cnp_line.at[ridx].set(fb.cnp_wire)
 
         # ------------------------------------------------ 11. CC update
-        cc = step_dcqcn(state.cc, fb.cnp_in, send, cfg, rtt_scale=rtt_scale,
-                        soft=soft)
+        with jax.named_scope("netsim.cc"):
+            cc = step_dcqcn(state.cc, fb.cnp_in, send, cfg,
+                            rtt_scale=rtt_scale, soft=soft)
 
         # ------------------------------------------------ 12. FCT
         # the completion latch stays HARD even in soft mode: the INF
         # sentinel makes any blend meaningless (forward exactness is the
         # contract; FCT gradients flow through the byte counters instead)
-        newly_done = (delivered >= total_bytes) & is_unfinished(
-            state.done_at_us)
-        done_at = jnp.where(newly_done, t_us, state.done_at_us)
+        with jax.named_scope("netsim.flow"):
+            newly_done = (delivered >= total_bytes) & is_unfinished(
+                state.done_at_us)
+            done_at = jnp.where(newly_done, t_us, state.done_at_us)
 
         if repair:
-            retx_line = state.retx_line.at[ridx].set(lost)
-            retx_inflight = state.retx_inflight + lost - retx_arr
+            with jax.named_scope("netsim.rings"):
+                retx_line = state.retx_line.at[ridx].set(lost)
+            with jax.named_scope("netsim.channel"):
+                retx_inflight = state.retx_inflight + lost - retx_arr
         else:
             retx_line, retx_inflight = None, None
 
@@ -839,68 +888,70 @@ def make_step_fn(cfg: NetConfig, wl: WorkloadParams, scheme,
         # is either delivered or sitting in exactly one queue / the pipe —
         # with a channel, also the loss-notification transit, the
         # retransmit backlog, or a jitter deferral buffer
-        q_dst_f = jnp.sum(q_dst, axis=0) if multi else q_dst
-        residual = sent - delivered - q_src - q_dst_f - q_leaf - inflight
-        if repair:
-            residual = residual - retx_inflight - retx_backlog
-        if impaired:
-            held = (jnp.sum(jax.vmap(channel.held_bytes)(chan_new), axis=0)
-                    if multi else channel.held_bytes(chan_new))
-            residual = residual - held
-        cons_err = jnp.max(jnp.abs(residual) / jnp.maximum(sent, 1.0))
-        if multi:
-            # capacity-weighted pause means keep the scalar trace keys (and
-            # the Fig. 3 pause-ratio column) shape-stable across L
-            cap_w = link_caps / jnp.maximum(jnp.sum(link_caps), 1e-9)
-            pause_trace = jnp.sum(pause_dst * cap_w)
-            src_paused_trace = jnp.sum(pause_sig * cap_w)
-        else:
-            pause_trace, src_paused_trace = pause_dst, pause_sig
-        out = {
-            "q_src": jnp.sum(q_src),
-            "q_dst": q_dst_tot,
-            "q_leaf": jnp.sum(q_leaf),
-            "pause_dst": pause_trace,
-            "src_paused": src_paused_trace,
-            "thr_inter": jnp.sum(drained_leaf * is_inter) / dt_s,
-            "thr_intra": jnp.sum(drained_leaf * is_intra) / dt_s,
-            "cons_err": cons_err,
-        }
-        if multi:
-            out.update({
-                "q_dst_link": q_dst_link,     # [L] per-link dst backlog
-                "link_tx": link_tx,           # [L] bytes launched per link
-                "link_pause": pause_dst,      # [L] per-link PFC state
-            })
-        if repair:
-            # engine-owned channel trace keys (goodput = wire - lost: with
-            # selective repair nothing delivered is ever a duplicate)
-            backlog_tot = jnp.sum(retx_backlog)
-            # granted repair capacity, floored at 1 MB/s: a transport
-            # whose window is momentarily exhausted still times out and
-            # retransmits eventually — without the floor a zero-rate step
-            # inflates the wait estimate to the histogram clamp
-            serv_cap = jnp.maximum(
-                jnp.sum(jnp.minimum(retx_bps, nic) * is_inter), 1e6)
-            d_us = d_steps.astype(jnp.float32) * dt_us
-            # fluid repair-latency estimate for the currently pending
-            # backlog: notification transit D + virtual drain time at the
-            # granted repair rate + retransmit transit D
-            wait_us = jnp.where(
-                backlog_tot > 0,
-                2.0 * d_us + backlog_tot / serv_cap * 1e6,
-                0.0)
-            out.update({
-                "chan_wire": jnp.sum(pipe_out),
-                "chan_lost": jnp.sum(lost),
-                "chan_retx": jnp.sum(retx_send),
-                "chan_backlog": backlog_tot,
-                "chan_repair_wait_us": wait_us,
-            })
-        if has_fail:
-            # the live mask as a trace key ([L] at multi; scalar at L=1)
-            out["fail_live"] = link_live if multi else link_live[0]
-        out.update(scheme.extra_traces(hctx, state))
+        with jax.named_scope("netsim.accumulators"):
+            q_dst_f = jnp.sum(q_dst, axis=0) if multi else q_dst
+            residual = sent - delivered - q_src - q_dst_f - q_leaf - inflight
+            if repair:
+                residual = residual - retx_inflight - retx_backlog
+            if impaired:
+                held = (jnp.sum(jax.vmap(channel.held_bytes)(chan_new), axis=0)
+                        if multi else channel.held_bytes(chan_new))
+                residual = residual - held
+            cons_err = jnp.max(jnp.abs(residual) / jnp.maximum(sent, 1.0))
+            if multi:
+                # capacity-weighted pause means keep the scalar trace keys (and
+                # the Fig. 3 pause-ratio column) shape-stable across L
+                cap_w = link_caps / jnp.maximum(jnp.sum(link_caps), 1e-9)
+                pause_trace = jnp.sum(pause_dst * cap_w)
+                src_paused_trace = jnp.sum(pause_sig * cap_w)
+            else:
+                pause_trace, src_paused_trace = pause_dst, pause_sig
+            out = {
+                "q_src": jnp.sum(q_src),
+                "q_dst": q_dst_tot,
+                "q_leaf": jnp.sum(q_leaf),
+                "pause_dst": pause_trace,
+                "src_paused": src_paused_trace,
+                "thr_inter": jnp.sum(drained_leaf * is_inter) / dt_s,
+                "thr_intra": jnp.sum(drained_leaf * is_intra) / dt_s,
+                "cons_err": cons_err,
+            }
+            if multi:
+                out.update({
+                    "q_dst_link": q_dst_link,     # [L] per-link dst backlog
+                    "link_tx": link_tx,           # [L] bytes launched per link
+                    "link_pause": pause_dst,      # [L] per-link PFC state
+                })
+            if repair:
+                # engine-owned channel trace keys (goodput = wire - lost: with
+                # selective repair nothing delivered is ever a duplicate)
+                backlog_tot = jnp.sum(retx_backlog)
+                # granted repair capacity, floored at 1 MB/s: a transport
+                # whose window is momentarily exhausted still times out and
+                # retransmits eventually — without the floor a zero-rate step
+                # inflates the wait estimate to the histogram clamp
+                serv_cap = jnp.maximum(
+                    jnp.sum(jnp.minimum(retx_bps, nic) * is_inter), 1e6)
+                d_us = d_steps.astype(jnp.float32) * dt_us
+                # fluid repair-latency estimate for the currently pending
+                # backlog: notification transit D + virtual drain time at the
+                # granted repair rate + retransmit transit D
+                wait_us = jnp.where(
+                    backlog_tot > 0,
+                    2.0 * d_us + backlog_tot / serv_cap * 1e6,
+                    0.0)
+                out.update({
+                    "chan_wire": jnp.sum(pipe_out),
+                    "chan_lost": jnp.sum(lost),
+                    "chan_retx": jnp.sum(retx_send),
+                    "chan_backlog": backlog_tot,
+                    "chan_repair_wait_us": wait_us,
+                })
+            if has_fail:
+                # the live mask as a trace key ([L] at multi; scalar at L=1)
+                out["fail_live"] = link_live if multi else link_live[0]
+            with jax.named_scope("hook.extra_traces"):
+                out.update(scheme.extra_traces(hctx, state))
         return new_state, out
 
     step.ctx = ctx      # shared per-run quantities for the metric machinery
@@ -941,28 +992,31 @@ def _scan_with_mode(step, scheme, channel, state0, steps: int, mode: str,
         def wstep(carry, t):
             state, acc, ring, ev = carry
             new_state, out = step(state, t)
-            inc = (t >= warm).astype(jnp.float32)
-            acc = _accumulate_engine(acc, out, inc)
-            acc = acc._replace(scheme=scheme.accumulate_metrics(
-                ctx, acc.scheme, new_state, out, inc))
-            if track_chan:
-                acc = acc._replace(chan=channel.accumulate_metrics(
-                    ctx, acc.chan, new_state, out, inc))
-            ring = {k: ring[k].at[jnp.mod(t, w)].set(out[k]) for k in ring}
-            if ev is not None:
-                cands = list(engine_event_candidates(ctx, state, new_state,
-                                                     t))
-                cands += list(scheme.emit_events(ctx, state, new_state,
-                                                 out))
-                if len(cands) > slots:
-                    raise ValueError(
-                        f"event_ring_slots={slots} is smaller than the "
-                        f"{len(cands)} per-step event candidates of this "
-                        f"run — raise NetConfig.event_ring_slots so one "
-                        f"step can never overflow the ring "
-                        f"(docs/observability.md)")
-                t_us = t.astype(jnp.float32) * ctx.dt_us
-                ev = push_events(ev, slots, t_us, cands)
+            with jax.named_scope("netsim.accumulators"):
+                inc = (t >= warm).astype(jnp.float32)
+                acc = _accumulate_engine(acc, out, inc)
+                with jax.named_scope("hook.accumulate_metrics"):
+                    acc = acc._replace(scheme=scheme.accumulate_metrics(
+                        ctx, acc.scheme, new_state, out, inc))
+                if track_chan:
+                    acc = acc._replace(chan=channel.accumulate_metrics(
+                        ctx, acc.chan, new_state, out, inc))
+                ring = {k: ring[k].at[jnp.mod(t, w)].set(out[k])
+                        for k in ring}
+                if ev is not None:
+                    cands = list(engine_event_candidates(ctx, state,
+                                                         new_state, t))
+                    cands += list(scheme.emit_events(ctx, state, new_state,
+                                                     out))
+                    if len(cands) > slots:
+                        raise ValueError(
+                            f"event_ring_slots={slots} is smaller than the "
+                            f"{len(cands)} per-step event candidates of "
+                            f"this run — raise NetConfig.event_ring_slots "
+                            f"so one step can never overflow the ring "
+                            f"(docs/observability.md)")
+                    t_us = t.astype(jnp.float32) * ctx.dt_us
+                    ev = push_events(ev, slots, t_us, cands)
             return (new_state, acc, ring, ev), None
 
         (final, acc, ring, ering), _ = jax.lax.scan(
@@ -975,13 +1029,15 @@ def _scan_with_mode(step, scheme, channel, state0, steps: int, mode: str,
         def mstep(carry, t):
             state, acc = carry
             state, out = step(state, t)
-            inc = (t >= warm).astype(jnp.float32)
-            acc = _accumulate_engine(acc, out, inc)
-            acc = acc._replace(scheme=scheme.accumulate_metrics(
-                step.ctx, acc.scheme, state, out, inc))
-            if track_chan:
-                acc = acc._replace(chan=channel.accumulate_metrics(
-                    step.ctx, acc.chan, state, out, inc))
+            with jax.named_scope("netsim.accumulators"):
+                inc = (t >= warm).astype(jnp.float32)
+                acc = _accumulate_engine(acc, out, inc)
+                with jax.named_scope("hook.accumulate_metrics"):
+                    acc = acc._replace(scheme=scheme.accumulate_metrics(
+                        step.ctx, acc.scheme, state, out, inc))
+                if track_chan:
+                    acc = acc._replace(chan=channel.accumulate_metrics(
+                        step.ctx, acc.chan, state, out, inc))
             return (state, acc), None
 
         k = step.ctx.cfg.remat_steps
@@ -1021,9 +1077,10 @@ def _scan_with_mode(step, scheme, channel, state0, steps: int, mode: str,
             # rate columns stay exact at any decimation.
             state, outs = jax.lax.scan(step, state,
                                        b * k + jnp.arange(k, dtype=jnp.int32))
-            return state, {key: (jnp.sum(v, axis=0)
-                                 if key in DECIMATE_SUM_KEYS else v[-1])
-                           for key, v in outs.items()}
+            with jax.named_scope("netsim.accumulators"):
+                return state, {key: (jnp.sum(v, axis=0)
+                                     if key in DECIMATE_SUM_KEYS else v[-1])
+                               for key, v in outs.items()}
 
         final, traces = jax.lax.scan(block, state0,
                                      jnp.arange(nblocks, dtype=jnp.int32))
@@ -1193,8 +1250,9 @@ def simulate_batch(cfgs: Sequence[NetConfig], workload, scheme,
     warm = int(steps * WARMUP_FRAC) if warm_steps is None else int(warm_steps)
     dp, hs = batch_padding(cfgs)
     delay_pad, history_slots = max(delay_pad, dp), max(history_slots, hs)
-    params = stack_net_params(cfgs)
-    wlp = as_workload_batch(workload, len(cfgs))
+    with span("netsim.stack", profile):
+        params = stack_net_params(cfgs)
+        wlp = as_workload_batch(workload, len(cfgs))
     if tmpl.is_multisite:
         from repro.netsim.topology import validate_site_endpoints
         validate_site_endpoints(tmpl, wlp)  # host-side: stalls fail early
@@ -1205,36 +1263,39 @@ def simulate_batch(cfgs: Sequence[NetConfig], workload, scheme,
     devs = list(devices) if devices is not None else jax.devices()
     put = (partial(jax.device_put, device=devs[0])
            if devices is not None and len(devs) == 1 else jnp.asarray)
-    params = NetParams(*(put(np.asarray(v)) for v in params))
-    wlp = WorkloadParams(*(put(np.asarray(v)) for v in wlp))
     b = len(cfgs)
     pad = (-b) % len(devs) if len(devs) > 1 else 0
-    if pad:
-        # pad-and-shard: replicate the last scenario until the device
-        # count divides the batch, run sharded, then strip the padded
-        # rows from every output leaf — a ragged batch no longer falls
-        # back silently to a single-device launch
-        def rep(x):
-            return jnp.concatenate([x, jnp.repeat(x[-1:], pad, axis=0)],
-                                   axis=0)
-        params = jax.tree.map(rep, params)
-        wlp = jax.tree.map(rep, wlp)
-    if len(devs) > 1:
-        params, wlp = shard_scenario_axis(params, wlp, devs)
-    if profile is not None:
-        from repro.netsim.obs.profile import profiled_traced_batch
-        profile.update(n_cells=b, pad=pad, n_devices=len(devs),
-                       steps=steps, trace_mode=trace_mode)
-        out = profiled_traced_batch(tmpl, params, wlp, scheme, steps,
+    with span("netsim.transfer", profile):
+        params = NetParams(*(put(np.asarray(v)) for v in params))
+        wlp = WorkloadParams(*(put(np.asarray(v)) for v in wlp))
+        if pad:
+            # pad-and-shard: replicate the last scenario until the device
+            # count divides the batch, run sharded, then strip the padded
+            # rows from every output leaf — a ragged batch no longer falls
+            # back silently to a single-device launch
+            def rep(x):
+                return jnp.concatenate([x, jnp.repeat(x[-1:], pad, axis=0)],
+                                       axis=0)
+            params = jax.tree.map(rep, params)
+            wlp = jax.tree.map(rep, wlp)
+        if len(devs) > 1:
+            params, wlp = shard_scenario_axis(params, wlp, devs)
+    with span("netsim.launch"):
+        if profile is not None:
+            from repro.netsim.obs.profile import profiled_traced_batch
+            profile.update(n_cells=b, pad=pad, n_devices=len(devs),
+                           steps=steps, trace_mode=trace_mode)
+            out = profiled_traced_batch(tmpl, params, wlp, scheme, steps,
+                                        period_slots, delay_pad,
+                                        history_slots, trace_mode, decimate,
+                                        warm, channel, profile)
+        else:
+            out = _run_traced_batch(tmpl, params, wlp, scheme, steps,
                                     period_slots, delay_pad, history_slots,
-                                    trace_mode, decimate, warm, channel,
-                                    profile)
-    else:
-        out = _run_traced_batch(tmpl, params, wlp, scheme, steps,
-                                period_slots, delay_pad, history_slots,
-                                trace_mode, decimate, warm, channel)
+                                    trace_mode, decimate, warm, channel)
     if pad:
-        out = jax.tree.map(lambda x: x[:b], out)
+        with span("netsim.rows", profile):
+            out = jax.tree.map(lambda x: x[:b], out)
     return out
 
 
@@ -1256,26 +1317,31 @@ def _run_traced_batch_impl(cfg, params, wlp, scheme, steps, period_slots,
     return jax.vmap(one_scenario)(params, wlp)
 
 
-def _jit_traced_batch(donate_argnums=()):
+def _jit_traced_batch(donate_argnums=(), fun=_run_traced_batch_impl):
     """The batch runner jitted with its static arguments; ``donate_argnums``
-    ``(1, 2)`` donates the stacked (params, workload) inputs."""
+    ``(1, 2)`` donates the stacked (params, workload) inputs. ``fun`` is
+    the runner or a wrapper with its signature (a new wrapper shares none
+    of JAX's in-process trace and lowering caches)."""
     return partial(jax.jit,
                    static_argnames=("cfg", "scheme", "steps", "period_slots",
                                     "delay_pad", "history_slots", "mode",
                                     "decimate", "warm", "channel"),
-                   donate_argnums=donate_argnums)(_run_traced_batch_impl)
+                   donate_argnums=donate_argnums)(fun)
+
+
+def _donated_inputs() -> tuple:
+    """The stacked batch inputs are donated so giant-grid chunk launches
+    reuse their buffers in place (XLA ignores donation on CPU and would
+    warn about it, hence none there). Initializes the backend."""
+    return () if jax.default_backend() == "cpu" else (1, 2)
 
 
 @lru_cache(maxsize=1)
 def _jitted_traced_batch():
     """Build the jitted batch runner on FIRST use, not at import: the
     donation decision needs ``jax.default_backend()``, which initializes
-    the backend — importing ``repro.netsim`` must never do that. The
-    stacked batch inputs are donated so giant-grid chunk launches reuse
-    their buffers in place (XLA ignores donation on CPU and would warn
-    about it, hence none there)."""
-    return _jit_traced_batch(() if jax.default_backend() == "cpu"
-                             else (1, 2))
+    the backend — importing ``repro.netsim`` must never do that."""
+    return _jit_traced_batch(_donated_inputs())
 
 
 def _run_traced_batch(*args, **kwargs):

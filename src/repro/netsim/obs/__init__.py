@@ -9,7 +9,7 @@ off:
   * **timeline export** (`timeline`): window/event/trace data -> Chrome
     trace-event JSON for Perfetto UI / ``chrome://tracing``
   * **launch profiling + manifests** (`profile`): AOT compile/execute
-    wall-clock split, XLA memory/cost figures, and JSONL run manifests
+    wall-clock split, XLA memory figures, and JSONL run manifests
     summarized by ``tools/obs_report.py``
 """
 from .events import (EVENT_KINDS, EventRing, decode_events,

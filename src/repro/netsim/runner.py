@@ -40,6 +40,7 @@ import numpy as np
 
 from repro.config.base import NetConfig, batch_template
 from repro.netsim.channel import get_channel_model
+from repro.netsim.obs.profile import span, write_manifest
 from repro.netsim.fluid import (
     WARMUP_FRAC, MetricAcc, batch_padding, hist_quantile, is_unfinished,
     simulate_batch,
@@ -566,10 +567,11 @@ def _run_launch(launch: _Launch, cfgs, wlp_np, grid_static, period_slots,
     routed to the AOT profiling path (filled in place with the launch's
     compile/execute split and memory figures — docs/observability.md)."""
     horizon, steps, warm, delay_pad, history_slots = grid_static
-    sub_cfgs = cfgs[launch.lo:launch.hi]
-    sub_wlp = WorkloadParams(*(v[launch.lo:launch.hi] for v in wlp_np))
-    n_real = len(sub_cfgs)
-    sub_cfgs, sub_wlp = _pad_chunk(sub_cfgs, sub_wlp, launch.pad_to)
+    with span("netsim.stack", profile):
+        sub_cfgs = cfgs[launch.lo:launch.hi]
+        sub_wlp = WorkloadParams(*(v[launch.lo:launch.hi] for v in wlp_np))
+        n_real = len(sub_cfgs)
+        sub_cfgs, sub_wlp = _pad_chunk(sub_cfgs, sub_wlp, launch.pad_to)
     try:
         final, aux = simulate_batch(
             sub_cfgs, sub_wlp, launch.scheme, horizon, period_slots,
@@ -597,21 +599,23 @@ def _run_launch(launch: _Launch, cfgs, wlp_np, grid_static, period_slots,
                 grid_static, period_slots, trace_mode, decimate, devices,
                 channel, n_dev, strict_conservation, conservation_tol))
         return rows
-    if strict_conservation:
-        _check_conservation(launch.scheme.name, aux, launch.lo, n_real,
-                            trace_mode, decimate, conservation_tol)
-    final_np = {"delivered": np.asarray(final.delivered),
-                "done_at_us": np.asarray(final.done_at_us)}
-    wl_np = WorkloadParams(*(np.asarray(v) for v in sub_wlp))
-    if trace_mode in ("metrics", "window"):
-        acc = aux if trace_mode == "metrics" else aux.acc
-        sub_rows = _metrics_streaming(sub_cfgs, wl_np, launch.scheme,
-                                      channel, final_np, acc, steps, warm)
-    else:
-        traces_np = {k: np.asarray(v) for k, v in aux.items()}
-        sub_rows = _metrics_batch(
-            sub_cfgs, wl_np, launch.scheme.name, final_np, traces_np,
-            decimate if trace_mode == "decimate" else 1)
+    with span("netsim.rows", profile):
+        if strict_conservation:
+            _check_conservation(launch.scheme.name, aux, launch.lo, n_real,
+                                trace_mode, decimate, conservation_tol)
+        final_np = {"delivered": np.asarray(final.delivered),
+                    "done_at_us": np.asarray(final.done_at_us)}
+        wl_np = WorkloadParams(*(np.asarray(v) for v in sub_wlp))
+        if trace_mode in ("metrics", "window"):
+            acc = aux if trace_mode == "metrics" else aux.acc
+            sub_rows = _metrics_streaming(sub_cfgs, wl_np, launch.scheme,
+                                          channel, final_np, acc, steps,
+                                          warm)
+        else:
+            traces_np = {k: np.asarray(v) for k, v in aux.items()}
+            sub_rows = _metrics_batch(
+                sub_cfgs, wl_np, launch.scheme.name, final_np, traces_np,
+                decimate if trace_mode == "decimate" else 1)
     return sub_rows[:n_real]
 
 
@@ -655,14 +659,16 @@ def _execute_plan(plan: Sequence[_Launch], cfgs, wlp: WorkloadParams,
         raise ValueError(
             f"on_nonfinite must be 'keep', 'quarantine' or 'raise', "
             f"got {on_nonfinite!r}")
-    wlp_np = [np.asarray(v) for v in wlp]
+    with span("netsim.stack"):
+        wlp_np = [np.asarray(v) for v in wlp]
     n_dev = len(devices) if devices is not None else len(jax.devices())
 
     fingerprint = None
     if checkpoint_dir is not None or manifest_path is not None:
-        fingerprint = _plan_fingerprint(plan, cfgs, wlp_np, grid_static,
-                                        period_slots, trace_mode, decimate,
-                                        channel)
+        with span("netsim.manifest"):
+            fingerprint = _plan_fingerprint(plan, cfgs, wlp_np, grid_static,
+                                            period_slots, trace_mode,
+                                            decimate, channel)
     if checkpoint_dir is not None:
         os.makedirs(checkpoint_dir, exist_ok=True)
     manifest = [] if manifest_path is not None else None
@@ -688,11 +694,12 @@ def _execute_plan(plan: Sequence[_Launch], cfgs, wlp: WorkloadParams,
                 f"abort_after_launches: aborting sweep after {executed} "
                 f"executed launches (crash-injection hook)")
         prof = {} if manifest is not None else None
-        sub_rows = _guard_nonfinite(
-            _run_launch(launch, cfgs, wlp_np, grid_static, period_slots,
-                        trace_mode, decimate, devices, channel, n_dev,
-                        strict_conservation, conservation_tol, prof),
-            launch.lo, on_nonfinite)
+        sub_rows = _run_launch(launch, cfgs, wlp_np, grid_static,
+                               period_slots, trace_mode, decimate, devices,
+                               channel, n_dev, strict_conservation,
+                               conservation_tol, prof)
+        with span("netsim.rows", prof):
+            sub_rows = _guard_nonfinite(sub_rows, launch.lo, on_nonfinite)
         if ckpt is not None:
             _write_checkpoint(ckpt, fingerprint, launch, sub_rows)
         executed += 1
@@ -703,7 +710,6 @@ def _execute_plan(plan: Sequence[_Launch], cfgs, wlp: WorkloadParams,
             manifest.append(prof)
         rows.setdefault(launch.scheme, []).extend(sub_rows)
     if manifest_path is not None:
-        from repro.netsim.obs.profile import write_manifest
         executed_recs = [m for m in manifest if not m.get("resumed")]
         header = {
             "fingerprint": fingerprint,
@@ -724,7 +730,8 @@ def _execute_plan(plan: Sequence[_Launch], cfgs, wlp: WorkloadParams,
             "total_execute_s": sum(m.get("execute_s", 0.0)
                                    for m in executed_recs),
         }
-        write_manifest(manifest_path, header, manifest)
+        with span("netsim.manifest"):
+            write_manifest(manifest_path, header, manifest)
     return rows
 
 
@@ -798,8 +805,10 @@ def run_experiment_batch(cfgs: Sequence[NetConfig], workload, scheme,
     cfgs = list(cfgs)
     scheme = get_scheme(scheme)
     channel = get_channel_model(channel)
-    wlp = as_workload_batch(workload, len(cfgs))
-    grid_static = _grid_static(cfgs, horizon_us, delay_pad, history_slots)
+    with span("netsim.stack"):
+        wlp = as_workload_batch(workload, len(cfgs))
+        grid_static = _grid_static(cfgs, horizon_us, delay_pad,
+                                   history_slots)
     n_dev = len(devices) if devices is not None else len(jax.devices())
     chunk = _auto_chunk_cells(grid_static[1], trace_mode, decimate,
                               chunk_cells, n_dev, cfgs[0].num_paths,
@@ -915,8 +924,9 @@ def sweep_grid(scenarios, workload=None, schemes=(),
             "(or positionally after the Scenario grid)")
     scheme_objs = [get_scheme(s) for s in schemes]
     channel = get_channel_model(channel)
-    wlp = as_workload_batch(wl, len(cfgs))
-    grid_static = _grid_static(cfgs, horizon_us, 0, 0)
+    with span("netsim.stack"):
+        wlp = as_workload_batch(wl, len(cfgs))
+        grid_static = _grid_static(cfgs, horizon_us, 0, 0)
     n_dev = len(devices) if devices is not None else len(jax.devices())
     chunk = _auto_chunk_cells(grid_static[1], trace_mode, decimate,
                               chunk_cells, n_dev, cfgs[0].num_paths,

@@ -5,7 +5,7 @@ A manifest is written by ``run_experiment_batch``/``sweep_grid``
 (``manifest_path=...``): one ``record: "header"`` line (git rev, plan
 sha256 fingerprint, backend, grid summary) followed by one
 ``record: "launch"`` line per device launch (scheme, cell range,
-compile/execute wall-clock split, XLA memory/cost figures).
+compile/execute wall-clock split, host span seconds, XLA memory figures).
 
 Usage:
     python tools/obs_report.py summarize MANIFEST.jsonl
