@@ -8,6 +8,10 @@
   cc_proxy.py   — DCQCN machine (sender / proxy / THEMIS variants)
   matchrdma.py  — the composed three-segment controller
 """
+# The modules here import ``repro.netsim.soft``, which runs the netsim
+# package first, and netsim imports these modules back: entering through
+# netsim is the order in which every module is complete when it is read.
+import repro.netsim  # noqa: F401
 from repro.core.budget import BudgetState, fair_share, init_budget, update_budget
 from repro.core.cc_proxy import DcqcnState, init_dcqcn, step_dcqcn, themis_rtt_scale
 from repro.core.estimator import (

@@ -8,7 +8,10 @@ Each slot (duration ``slot_us``) aggregates three local observables:
 
 ``classify_slot`` compares ACK return time and CNP frequency against preset
 thresholds to assess the slot congestion level; ``SlotRing`` keeps the
-recent history the estimator aggregates into windows.
+recent history the estimator aggregates into windows, oldest-first as a
+shift register (a push shifts every slot down one and writes the newest
+last), so reading it in order needs no index arithmetic and, under
+``vmap``, no per-cell gather or scatter.
 """
 from __future__ import annotations
 
@@ -31,11 +34,11 @@ class SlotObs(NamedTuple):
 
 
 class SlotRing(NamedTuple):
-    """Ring buffer of the last R slots (leading axis R)."""
+    """Oldest-first shift register of the last R slots (leading axis R;
+    position R-1 is the newest)."""
     rates: jax.Array          # [R] egress rates
     congested: jax.Array      # [R] bool-ish f32 congestion flags
     busy: jax.Array           # [R] backlog present => egress == capability
-    idx: jax.Array            # scalar int32 — next write position
     count: jax.Array          # scalar int32 — total slots ever written
 
 
@@ -44,7 +47,6 @@ def init_ring(num_slots: int) -> SlotRing:
         rates=jnp.zeros((num_slots,), jnp.float32),
         congested=jnp.zeros((num_slots,), jnp.float32),
         busy=jnp.zeros((num_slots,), jnp.float32),
-        idx=jnp.int32(0),
         count=jnp.int32(0),
     )
 
@@ -90,20 +92,23 @@ def push_slot(ring: SlotRing, obs: SlotObs, cfg: NetConfig,
     else:
         congested = soft_pos(level, soft, 0.5)
     return SlotRing(
-        rates=ring.rates.at[ring.idx].set(obs.egress_rate.astype(jnp.float32)),
-        congested=ring.congested.at[ring.idx].set(congested),
-        busy=ring.busy.at[ring.idx].set(busy),
-        idx=jnp.mod(ring.idx + 1, ring.rates.shape[0]),
+        rates=_shift_in(ring.rates, obs.egress_rate.astype(jnp.float32)),
+        congested=_shift_in(ring.congested, congested),
+        busy=_shift_in(ring.busy, busy),
         count=ring.count + 1,
     )
 
 
+def _shift_in(hist: jax.Array, new: jax.Array) -> jax.Array:
+    """Drop the oldest slot and append ``new`` as the newest."""
+    return jnp.concatenate([hist[1:], jnp.asarray(new, hist.dtype)[None]])
+
+
 def ordered_history(ring: SlotRing) -> tuple:
-    """(rates, congested, busy, valid) with oldest-first ordering."""
+    """(rates, congested, busy, valid) with oldest-first ordering — the
+    stored order."""
     r = ring.rates.shape[0]
-    order = jnp.mod(ring.idx + jnp.arange(r), r)    # oldest .. newest
     valid_n = jnp.minimum(ring.count, r)
-    # positions [r - valid_n, r) of the ordered view are valid
+    # positions [r - valid_n, r) hold slots written so far
     valid = jnp.arange(r) >= (r - valid_n)
-    return (ring.rates[order], ring.congested[order], ring.busy[order],
-            valid.astype(jnp.float32))
+    return ring.rates, ring.congested, ring.busy, valid.astype(jnp.float32)
