@@ -1,5 +1,19 @@
 """What decides ``correct``: the rows the timed grids produced, against the
-plain reference (``bench/reference``) run after the window closed.
+plain reference that the cell's configuration names under ``"reference"``
+(a Python file, loaded by its path), run after the window closed.
+
+Every reference module provides two functions:
+
+* ``simulate_rows(scheme, cells, grid_nets, horizon_us, dtype, device)``:
+  the rows of ``cells`` (``bench/grid.py`` cell dicts) under ``scheme``,
+  one per cell in order, each with the program's row columns.
+  ``grid_nets`` are the nets of the whole grid (statics shared across
+  it), ``dtype`` the float type of every quantity (the configuration's
+  precision, or the control's one below it), ``device`` where to run
+  (``None``: JAX's default).
+* ``refuse_unmodelled(config, cells)``: raises ``ValueError``, naming the
+  field, where the configuration or a cell sets anything the module does
+  not model. ``grid.Cell`` calls it as it loads, before any chip work.
 
 * Every grid of the window must return every row (one per cell and
   scheme, in grid order), with every column finite (``avg_fct_us`` may be
@@ -79,15 +93,15 @@ def changed_rows(rows: list, baseline: list) -> int:
 
 def reference_rows(cell, cells: list, picks: dict, device=None,
                    dtype=None) -> dict:
-    """scheme -> {cell index: reference row}."""
+    """scheme -> {cell index: reference row}, from the cell's own
+    reference module (``cell.reference``)."""
     import jax.numpy as jnp
-    from bench.reference import fluid
     nets = [c["net"] for c in cells]
     out = {}
     for s, idx in picks.items():
-        rows = fluid.simulate_rows(s, [cells[i] for i in idx], nets,
-                                   cell.horizon_us,
-                                   dtype=dtype or jnp.float32, device=device)
+        rows = cell.reference.simulate_rows(
+            s, [cells[i] for i in idx], nets, cell.horizon_us,
+            dtype=dtype or jnp.float32, device=device)
         out[s] = dict(zip(idx, rows))
     return out
 

@@ -8,7 +8,9 @@ reference in float32 on the seed's sample of cells (the lower reading:
 the program's ``row_gap``), then, for the first ``--control-seeds`` seeds,
 the control: the same reference computed in bfloat16, one precision below
 the configuration's float32, put in the program's place and judged against
-the float32 reference (the upper reading). Prints one JSON line per seed
+the float32 reference (the upper reading). The reference is the one the
+cell's configuration names, resolved as a benchmark run resolves it
+(``grid.Cell``, ``check.reference_rows``). Prints one JSON line per seed
 and writes them all to ``--out``. Not part of a benchmark run.
 """
 from __future__ import annotations
@@ -38,7 +40,8 @@ def readings(cell, seed: int, device, control: bool) -> dict:
     refs = check.reference_rows(cell, sweep.cells, picks, device=device)
     t2 = time.perf_counter()
     g = check.gaps(rows, refs, cell.schemes)
-    out = {"seed": seed, "program_gap": max(g)[0],
+    out = {"seed": seed, "reference": cell.config["reference"],
+           "program_gap": max(g)[0],
            "program_worst": list(max(g)[1:]),
            "bad_rows": check.bad_rows(rows, sweep.cells, cell.schemes),
            "grid_s": t1 - t0, "reference_s": t2 - t1}

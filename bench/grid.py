@@ -19,7 +19,10 @@ Mix file keys:
                 unbounded), and the on/off phase either in microseconds
                 (``start_us``, ``period_us``, ``on_us``) or as fractions
                 of the horizon (``start_of_horizon``, ... as ``[num,
-                den]``).
+                den]``). Any other key (``route``, ``src_site``, a field
+                a later program adds) is copied into each flow dict as it
+                stands; ``to_program`` hands it to ``FlowSpec`` and
+                refuses a key that names no ``FlowSpec`` field.
 ``seed``        what the seed may draw, per group: ``shift_of_horizon:
                 [num, den]`` moves the group's start by a uniform draw in
                 +-(num/den) of the horizon, once per cell;
@@ -31,6 +34,9 @@ every seed gives the same work.
 """
 from __future__ import annotations
 
+import copy
+import dataclasses
+import importlib.util
 import itertools
 import json
 import os
@@ -38,11 +44,42 @@ import os
 import numpy as np
 
 UNBOUNDED = 1e18
+# the generator's own group keys; every other group key is a flow field
+GROUP_KEYS = frozenset(
+    ["count", "is_inter", "msg_size", "concurrency", "total_bytes", "seed"]
+    + [k + u for k in ("start", "period", "on")
+       for u in ("_us", "_of_horizon")])
 
 
 def load_json(path: str) -> dict:
     with open(path) as f:
         return json.load(f)
+
+
+def load_module(path: str, name: str):
+    """The Python file at ``path``, loaded as a module called ``name``."""
+    if not os.path.isfile(path):
+        raise FileNotFoundError(f"bench: no module file {path!r}")
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def load_reference(root: str, config: dict):
+    """The plain reference module the configuration names under
+    ``"reference"`` (a path from the checkout's root)."""
+    if "reference" not in config:
+        raise ValueError(f"bench: configuration {config.get('name')!r} "
+                         f"names no reference")
+    path = os.path.join(root, config["reference"])
+    return load_module(path, "bench_reference_" + "".join(
+        c if c.isalnum() else "_" for c in config["reference"]))
+
+
+def _tupled(v):
+    """Lists, at any depth, as tuples (the program's config values)."""
+    return tuple(_tupled(x) for x in v) if isinstance(v, list) else v
 
 
 def _of(group: dict, key: str, horizon: float, default=None):
@@ -62,6 +99,7 @@ def _flows(groups: list, horizon: float, override: dict, rng) -> list:
         period = _of(g, "period", horizon, 0.0)
         on = _of(g, "on", horizon, period)
         draw = g.get("seed", {})
+        extra = {k: v for k, v in g.items() if k not in GROUP_KEYS}
         if "shift_of_horizon" in draw and rng is not None:
             num, den = draw["shift_of_horizon"]
             start += rng.uniform(-1.0, 1.0) * horizon * num / den
@@ -70,14 +108,20 @@ def _flows(groups: list, horizon: float, override: dict, rng) -> list:
             if "offset_us" in draw and rng is not None:
                 s += rng.uniform(0.0, float(draw["offset_us"]))
             msg, conc = float(g["msg_size"]), int(g["concurrency"])
-            flows.append({
+            flow = {
                 "is_inter": 1.0 if g["is_inter"] else 0.0,
                 "msg_size": msg, "concurrency": conc,
                 "window": msg * conc,
                 "total_bytes": float(g.get("total_bytes", UNBOUNDED)),
                 "start_us": s, "period_us": period,
                 "duty": on / period if period > 0 else 1.0,
-            })
+            }
+            for k, v in extra.items():
+                if k in flow:
+                    raise ValueError(f"bench: flow group key {k!r} is "
+                                     f"derived by the generator")
+                flow[k] = copy.deepcopy(v)
+            flows.append(flow)
     return flows
 
 
@@ -101,27 +145,39 @@ def build(config: dict, mix: dict, seed: int) -> list:
     return cells
 
 
+def flow_spec(flow: dict):
+    """The program's ``FlowSpec`` for one flow dict: every key that is a
+    ``FlowSpec`` field, lists as tuples; ``window``, which the generator
+    derives for the reference, is left out; any other key raises."""
+    from repro.netsim.workload import FlowSpec
+    fields = {f.name: f for f in dataclasses.fields(FlowSpec)}
+    kw = {}
+    for k, v in flow.items():
+        if k == "window":
+            continue
+        if k not in fields:
+            raise ValueError(f"bench: flow key {k!r} is not a field of the "
+                             f"program's FlowSpec")
+        kw[k] = bool(v) if fields[k].type in (bool, "bool") else _tupled(v)
+    return FlowSpec(**kw)
+
+
 def to_program(cells: list):
     """The program's ``Scenario`` objects for ``cells``."""
     from repro.config.base import NetConfig
     from repro.netsim import Scenario
-    from repro.netsim.workload import FlowSpec, Workload
-    out = []
-    for c in cells:
-        net = {k: tuple(tuple(x) if isinstance(x, list) else x for x in v)
-               if isinstance(v, list) else v for k, v in c["net"].items()}
-        flows = tuple(FlowSpec(
-            is_inter=bool(f["is_inter"]), msg_size=f["msg_size"],
-            concurrency=f["concurrency"], total_bytes=f["total_bytes"],
-            start_us=f["start_us"], period_us=f["period_us"],
-            duty=f["duty"]) for f in c["flows"])
-        out.append(Scenario(NetConfig(**net), Workload(flows)))
-    return out
+    from repro.netsim.workload import Workload
+    return [Scenario(NetConfig(**{k: _tupled(v) for k, v in c["net"].items()}),
+                     Workload(tuple(flow_spec(f) for f in c["flows"])))
+            for c in cells]
 
 
 class Cell:
     """One ``workloads`` entry of BENCHMARK.json, resolved by name: its
-    configuration file, its traffic-mix file and the grid they make."""
+    configuration file, its traffic-mix file, the grid they make and the
+    plain reference the configuration names. Loading refuses, before any
+    chip work, a cell whose reference is missing or does not model what
+    the cell sets (the reference's ``refuse_unmodelled``)."""
 
     def __init__(self, root: str, name: str):
         bench = load_json(os.path.join(root, "BENCHMARK.json"))
@@ -135,6 +191,9 @@ class Cell:
             entry["traffic"] + ".json"))
         self.horizon_us = float(self.mix["horizon_us"])
         self.schemes = tuple(self.mix["schemes"])
+        self.reference = load_reference(root, self.config)
+        # the seed moves only start times, so seed 0 stands for every seed
+        self.reference.refuse_unmodelled(self.config, self.cells(0))
 
     def cells(self, seed: int) -> list:
         return build(self.config, self.mix, seed)

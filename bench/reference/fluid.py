@@ -35,6 +35,7 @@ columns.
 from __future__ import annotations
 
 import math
+import os
 from functools import partial
 
 import jax
@@ -55,6 +56,73 @@ FAST_RECOVERY = 5
 # schemes whose sender-side DCQCN does not limit inter-DC flows
 WINDOW_ONLY = ("matchrdma", "geopipe")
 BUDGET_BLOCK = ("dcqcn", "themis", "pseudo_ack", "matchrdma", "rdmacell")
+
+
+# ------------------------------------------------------ what it models
+
+# flow keys it reads, at any value
+FLOW_KEYS = ("is_inter", "msg_size", "concurrency", "window", "total_bytes",
+             "start_us", "period_us", "duty")
+# flow keys it accepts only at the program's default: every flow sprays
+# over all links by equal weight, from site 0 to site 1
+FLOW_AT_DEFAULT = {"route": [], "src_site": 0, "dst_site": 1}
+# network fields it reads, at any value
+NET_KEYS = (
+    "num_otn_links", "link_gbps", "intra_dc_delay_us", "distance_km",
+    "dst_dc_gbps", "nic_gbps", "num_paths", "path_delay_scale",
+    "path_cap_frac", "dt_us", "ecn_kmin_kb", "ecn_kmax_kb", "ecn_pmax",
+    "dcqcn_g", "dcqcn_rai_mbps", "dcqcn_hai_mbps", "dcqcn_alpha_timer_us",
+    "dcqcn_rate_timer_us", "dcqcn_bytes_counter_mb", "cnp_interval_us",
+    "min_rate_mbps", "pfc_xoff_kb", "pfc_xon_kb", "otn_buffer_bdp_frac",
+    "slot_us", "slots_per_window", "ack_delay_thresh_us", "cnp_freq_thresh",
+    "queue_thresh_kb", "stable_cv_thresh", "stable_weight", "jitter_weight",
+    "budget_headroom", "budget_probe", "budget_floor_mbps",
+    "control_proc_slots", "geopipe_credit_bdp_frac", "sdr_window_bdp_frac",
+    "sdr_ack_coalesce_us", "sdr_retx_budget_frac",
+    "rdmacell_token_bucket_us", "rdmacell_rob_limit_mb")
+# network fields it accepts only at the program's default: one site pair,
+# per-link PFC at pfc_xoff_kb, no schedule, no failure, no impairment,
+# the hard step
+NET_AT_DEFAULT = {
+    "path_thresh_kb": [], "num_sites": 2, "site_edges": [],
+    "channel_schedule": [], "channel_schedule_dt_us": 0.0,
+    "failure_schedule": [], "loss_rate": 0.0, "loss_burst_len": 1.0,
+    "jitter_us": 0.0, "flap_period_us": 0.0, "flap_depth": 0.0,
+    "channel_seed": 0, "soft_step": False, "remat_steps": 0}
+CHANNELS = ("ideal",)
+_WHO = "reference " + os.path.basename(__file__)
+
+
+def _plain(v):
+    return [_plain(x) for x in v] if isinstance(v, (list, tuple)) else v
+
+
+def _refuse(where: str, items: dict, keys, at_default: dict) -> None:
+    for k, v in items.items():
+        if k in keys:
+            continue
+        if k not in at_default:
+            raise ValueError(f"{_WHO}: {where} key {k!r} is "
+                             f"not modelled")
+        if _plain(v) != at_default[k]:
+            raise ValueError(f"{_WHO}: {where} {k!r} = {v!r} "
+                             f"is not modelled (only {at_default[k]!r})")
+
+
+def refuse_unmodelled(config: dict, cells: list) -> None:
+    """Raise ``ValueError``, naming the field, where the configuration or a
+    cell sets anything this reference does not model: a channel other
+    than ``ideal``, a network field outside ``NET_KEYS`` (or one of
+    ``NET_AT_DEFAULT`` off its default), a flow key outside ``FLOW_KEYS``
+    (or one of ``FLOW_AT_DEFAULT`` off its default)."""
+    channel = config.get("channel", "ideal")
+    if channel not in CHANNELS:
+        raise ValueError(f"{_WHO}: channel {channel!r} is not "
+                         f"modelled (only {', '.join(CHANNELS)})")
+    for c in cells:
+        _refuse("net", c["net"], NET_KEYS, NET_AT_DEFAULT)
+        for f in c["flows"]:
+            _refuse("flow", f, FLOW_KEYS, FLOW_AT_DEFAULT)
 
 
 # ---------------------------------------------------------------- statics

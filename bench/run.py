@@ -9,8 +9,16 @@ back through ``repro.netsim.sweep_grid(..., trace_mode="metrics")`` until
 the first grid that ends after ``--seconds``. ``scenario_steps_per_s`` is
 cells x scan steps of every grid in the window over the window's wall
 time. After the window the rows of a seeded sample of cells are compared
-with the plain reference (``bench/reference``); ``correct`` says whether
-every row is present, finite and within the limit.
+with the plain reference the configuration names (``bench/check.py``);
+``correct`` says whether every row is present, finite and within the
+limit. The configuration's ``"channel"`` is the channel every cell runs
+on (``ideal``: the program's default, passed as nothing).
+
+Each window grid's record (the ``grids`` key of the result line) holds its
+wall, the process's CPU seconds and involuntary context switches over it,
+and the CPU quota's throttled microseconds over it (cgroup ``cpu.stat``;
+``null`` where the host has none): a grid that stalled while the process
+was throttled waited on the quota, not on the program.
 
 ``--trace 1`` routes the same grids through launch manifests and the JAX
 profiler and reports the per-layer metrics instead, each read by its own
@@ -28,9 +36,9 @@ import time
 T0 = time.perf_counter()
 
 import argparse   # noqa: E402
-import importlib.util   # noqa: E402
 import json   # noqa: E402
 import os   # noqa: E402
+import resource   # noqa: E402
 import shutil   # noqa: E402
 import sys   # noqa: E402
 import tempfile   # noqa: E402
@@ -45,6 +53,8 @@ from repro.netsim.obs.profile import configure_compile_cache   # noqa: E402
 
 PLATFORM = "tpu"
 SPAN_SETUP, SPAN_GRID = "bench.setup", "bench.grid"
+# the cgroup's CPU statistics (v2): time its CPU quota held it back
+CPU_STAT, THROTTLED = "/sys/fs/cgroup/cpu.stat", "throttled_usec"
 
 
 def device_check(chips: int, platform: str = PLATFORM):
@@ -81,12 +91,9 @@ class CompileCounter:
 
 def load_reader(root: str, name: str):
     """The per-layer metric module ``bench/metrics/<name>.py``."""
-    path = os.path.join(root, "bench", "metrics", name + ".py")
-    spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+    return grid.load_module(os.path.join(root, "bench", "metrics",
+                                         name + ".py"),
+                            "bench_metric_" + name.replace(".", "_"))
 
 
 def metric_entries(root: str, section: str, workload: str) -> list:
@@ -100,37 +107,70 @@ class Sweep:
     """The timed entry: one whole grid per call, as a user sweeps it."""
 
     def __init__(self, cell: grid.Cell, seed: int):
+        from repro.netsim import get_channel_model
         self.cell = cell
         self.cells = cell.cells(seed)
         self.scenarios = grid.to_program(self.cells)
         self.n_rows = len(self.cells) * len(cell.schemes)
+        name = cell.config.get("channel", "ideal")
+        self.channel = None if name == "ideal" else get_channel_model(name)
 
     def __call__(self, manifest_path=None, schemes=None):
         from repro.netsim import sweep_grid
         return sweep_grid(self.scenarios, schemes or self.cell.schemes,
                           horizon_us=self.cell.horizon_us,
-                          trace_mode="metrics", manifest_path=manifest_path)
+                          trace_mode="metrics", channel=self.channel,
+                          manifest_path=manifest_path)
+
+
+def throttled_us():
+    """Microseconds the CPU quota has throttled this process's cgroup, or
+    None where the host keeps no such count."""
+    try:
+        with open(CPU_STAT) as f:
+            for ln in f:
+                k, _, v = ln.partition(" ")
+                if k == THROTTLED:
+                    return int(v)
+    except OSError:
+        pass
+    return None
+
+
+def host_usage() -> tuple:
+    """(process CPU seconds, involuntary context switches, throttled us)."""
+    ru = resource.getrusage(resource.RUSAGE_SELF)
+    return ru.ru_utime + ru.ru_stime, ru.ru_nivcsw, throttled_us()
+
+
+def grid_record(wall_s: float, before: tuple, after: tuple) -> dict:
+    """One window grid's wall and what the host did meanwhile."""
+    thr = (None if before[2] is None or after[2] is None
+           else after[2] - before[2])
+    return {"wall_s": wall_s, "cpu_s": after[0] - before[0],
+            "nivcsw": after[1] - before[1], "throttled_us": thr}
 
 
 def run_window(sweep: Sweep, seconds: float, manifests: str = None):
     """Whole grids back to back until one ends after ``seconds``.
-    Returns (rows of each grid, wall of each grid, window wall, manifest
+    Returns (rows of each grid, each grid's record, window wall, manifest
     paths)."""
     import jax
-    grids, walls, paths = [], [], []
+    grids, records, paths = [], [], []
     start = time.perf_counter()
     while True:
         path = (os.path.join(manifests, f"grid{len(grids)}.jsonl")
                 if manifests else None)
+        before = host_usage()
         t0 = time.perf_counter()
         with jax.profiler.TraceAnnotation(SPAN_GRID):
             rows = sweep(path)
         t1 = time.perf_counter()
         grids.append(rows)
-        walls.append(t1 - t0)
+        records.append(grid_record(t1 - t0, before, host_usage()))
         paths.append(path)
         if t1 - start >= seconds:
-            return grids, walls, t1 - start, paths
+            return grids, records, t1 - start, paths
 
 
 def peak_bytes(devices) -> int:
@@ -167,12 +207,12 @@ def traced_observations(sweep: Sweep, seconds: float, setup_manifest: str,
     import jax
     from repro.netsim.obs.profile import read_manifest
     from bench import trace_reduce
-    grids, walls, window_s, paths = run_window(sweep, seconds, tmp)
+    grids, records, window_s, paths = run_window(sweep, seconds, tmp)
     setup_launches = read_manifest(setup_manifest)[1]
     traced = heaviest_scheme(setup_launches)
     obs = {"steps": sweep.cell.steps(), "setup_launches": setup_launches,
-           "grids": [{"wall_s": w, "launches": read_manifest(p)[1]}
-                     for w, p in zip(walls, paths)]}
+           "grids": [dict(r, launches=read_manifest(p)[1])
+                     for r, p in zip(records, paths)]}
     tdir = os.path.join(tmp, "trace")
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
@@ -184,7 +224,7 @@ def traced_observations(sweep: Sweep, seconds: float, setup_manifest: str,
     obs["trace"] = trace_reduce.reduce_dir(tdir, (SPAN_SETUP, SPAN_GRID))
     print(f"bench: traced the {traced} sweep; trace and its reduction took "
           f"{time.perf_counter() - t0!r} s", file=sys.stderr, flush=True)
-    return grids, walls, window_s, obs
+    return grids, records, window_s, obs
 
 
 def main(argv=None, root: str = ROOT) -> int:
@@ -221,16 +261,20 @@ def measure(args, root, cell, sweep, devices, counter, tmp) -> dict:
 
     counter.armed = True
     if args.trace:
-        grids, walls, window_s, obs = traced_observations(
+        grids, records, window_s, obs = traced_observations(
             sweep, args.seconds, setup_manifest, tmp)
     else:
-        grids, walls, window_s, _ = run_window(sweep, args.seconds)
+        grids, records, window_s, _ = run_window(sweep, args.seconds)
     counter.armed = False
     peak = peak_bytes(devices)
     print(f"bench: {len(grids)} grids of {sweep.n_rows} rows in "
           f"{window_s!r} s; set-up {setup_s!r} s ({ready_s!r} s to the "
           f"set-up grid); grid walls "
-          f"{[round(w, 3) for w in walls]}", file=sys.stderr, flush=True)
+          f"{[round(r['wall_s'], 3) for r in records]}; CPU s "
+          f"{[round(r['cpu_s'], 3) for r in records]}; involuntary switches "
+          f"{[r['nivcsw'] for r in records]}; throttled us "
+          f"{[r['throttled_us'] for r in records]}", file=sys.stderr,
+          flush=True)
 
     verdict = check.judge(cell, sweep.cells, [warm_rows] + grids, args.seed,
                           devices[0], compiles=counter.n)
@@ -263,6 +307,7 @@ def measure(args, root, cell, sweep, devices, counter, tmp) -> dict:
             "device": device}
     if breakdown is not None:
         line["breakdown"] = breakdown
+    line["grids"] = records
     line["checks"] = verdict["checks"]
     return line
 

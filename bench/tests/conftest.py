@@ -22,20 +22,21 @@ def tiny_root(tmp_path_factory):
 
 @pytest.fixture
 def drive(tiny_root, monkeypatch, capsys):
-    """Run bench/run.py's main on the CPU over the cut-down checkout, the
-    chip check and the persistent cache skipped; returns the result line."""
+    """Run bench/run.py's main on the CPU over the cut-down checkout (or
+    ``root=``), the chip check and the persistent cache skipped; returns
+    the result line."""
     import jax
     import bench.run as run
     monkeypatch.setattr(run, "device_check",
                         lambda chips: jax.devices("cpu")[:chips])
     monkeypatch.setattr(run, "configure_compile_cache", lambda root: None)
 
-    def go(*extra):
+    def go(*extra, root=None):
         args = ["--workload", DRIVE_CELL, "--seed", str(2 ** 31 + 77),
                 "--seconds", "0.1"] + list(extra)
         jax.clear_caches()
         try:
-            assert run.main(args, root=tiny_root) == 0
+            assert run.main(args, root=root or tiny_root) == 0
         finally:
             jax.clear_caches()
         return json.loads(capsys.readouterr().out.strip().splitlines()[-1])

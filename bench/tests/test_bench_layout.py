@@ -6,11 +6,13 @@ import shutil
 import pytest
 
 from bench import grid
+from bench.tests import tiny
 import bench.run as run
 
 ROOT = run.ROOT
 BENCH = grid.load_json(os.path.join(ROOT, "BENCHMARK.json"))
-CELLS = [w["name"] for w in BENCH["workloads"]]
+# the benchmark's cells, and those it left out but keeps the mixes of
+CELLS = sorted({w["name"] for w in BENCH["workloads"] + tiny.PARKED})
 ALL = ("dcqcn", "pseudo_ack", "themis", "matchrdma", "geopipe", "sdr_rdma",
        "rdmacell")
 KM = [1.0, 10.0, 50.0, 100.0, 300.0, 500.0, 1000.0]
@@ -24,9 +26,15 @@ SOURCE = {
 }
 
 
+@pytest.fixture(scope="module")
+def full_root(tmp_path_factory):
+    """The benchmark at its own sizes, its left-out cells back in."""
+    return tiny.full(ROOT, str(tmp_path_factory.mktemp("full")))
+
+
 @pytest.mark.parametrize("name", CELLS)
-def test_cell_resolves_by_name(name):
-    cell = grid.Cell(ROOT, name)
+def test_cell_resolves_by_name(full_root, name):
+    cell = grid.Cell(full_root, name)
     assert cell.chips in (1, 4)
     assert cell.schemes and cell.horizon_us > 0
     assert "row_gap" in cell.config["limits"]
@@ -34,9 +42,9 @@ def test_cell_resolves_by_name(name):
 
 
 @pytest.mark.parametrize("name", sorted(SOURCE))
-def test_grid_matches_source(name):
+def test_grid_matches_source(full_root, name):
     n, km, steps, schemes, links = SOURCE[name]
-    cell = grid.Cell(ROOT, name)
+    cell = grid.Cell(full_root, name)
     cells = cell.cells(0)
     assert len(cells) == n
     assert [c["net"]["distance_km"] for c in cells] == km
@@ -56,9 +64,9 @@ def test_fig3cd_seed0_is_the_congestion_workload():
     assert all(s.workload == want for s in got)
 
 
-def test_fig3b_seed0_is_the_throughput_workload():
+def test_fig3b_seed0_is_the_throughput_workload(full_root):
     from repro.netsim.workload import throughput_workload
-    cell = grid.Cell(ROOT, "fig3b_msgsize")
+    cell = grid.Cell(full_root, "fig3b_msgsize")
     msgs = (1 << 10, 16 << 10, 64 << 10, 256 << 10, 1 << 20, 8 << 20)
     got = grid.to_program(cell.cells(0))
     want = [throughput_workload(m, 1, 4) for _ in KM for m in msgs]
@@ -66,8 +74,8 @@ def test_fig3b_seed0_is_the_throughput_workload():
 
 
 @pytest.mark.parametrize("name", sorted(SOURCE))
-def test_seed_moves_only_start_times(name):
-    cell = grid.Cell(ROOT, name)
+def test_seed_moves_only_start_times(full_root, name):
+    cell = grid.Cell(full_root, name)
     base, seeded = cell.cells(0), cell.cells(2 ** 31 + 12345)
     assert seeded == cell.cells(2 ** 31 + 12345)
     assert len(base) == len(seeded)
