@@ -78,6 +78,13 @@ Execution modes (``trace_mode``):
                 ``(final, WindowAux)``; ``repro.netsim.obs`` decodes rings
                 and exports Perfetto timelines (docs/observability.md).
 
+Lock-step rounds (docs/rounds.md):
+  A flow with ``round_bytes > 0`` sends round by round: round R+1 is
+  released ``round_period_us`` after round R at the earliest and not
+  before round R is delivered, and it never sends more than is posted.
+  The gate is built only when the grid has such a flow (the STATIC
+  ``rounds`` switch), under the ``netsim.rounds`` scope.
+
 Device sharding: ``shard_scenario_axis`` splits the stacked [B] scenario
 leaves across ``jax.devices()`` (jax.sharding over the vmapped axis), and
 ``simulate_batch`` applies it automatically whenever the device count
@@ -110,7 +117,9 @@ from repro.netsim.schemes.base import Scheme, SchemeCtx, SchemeSignals
 from repro.netsim.streaming import (
     HIST_BINS, hist_bin_centers, hist_bin_index, hist_quantile, kahan_add,
 )
-from repro.netsim.workload import WorkloadParams, as_workload_batch
+from repro.netsim.workload import (
+    WorkloadParams, as_workload_batch, has_rounds,
+)
 
 MTU = 1500.0
 # np (not jnp): a module-level jax array would initialize the backend at
@@ -262,6 +271,12 @@ class SimState(NamedTuple):
     retx_backlog: object     # [F] lost bytes awaiting retransmission at src
     retx_line: object        # [Dp, F] loss notifications dst -> src
     retx_inflight: object    # [F] running sum of retx_line (incremental)
+    # lock-step rounds (docs/rounds.md; ALL None unless the workload has
+    # rounds — the round gate is then structurally absent):
+    round_r: object = None       # [F] rounds released so far
+    round_due_us: object = None  # [F] earliest time of the next release
+    stall_us: object = None      # [F] time spent due but undelivered
+    round_late: object = None    # [F] releases made after their due step
 
 
 def _delay_steps(cfg: NetConfig) -> int:
@@ -276,13 +291,15 @@ def _proc_steps(cfg: NetConfig) -> int:
 
 def init_state(cfg: NetConfig, num_flows: int, params: NetParams = None,
                delay_pad: int = 0, history_slots: int = 0,
-               scheme: Scheme = None, channel: ChannelModel = None
-               ) -> SimState:
+               scheme: Scheme = None, channel: ChannelModel = None,
+               round_wl: WorkloadParams = None) -> SimState:
     """``delay_pad``/``history_slots`` are static ring sizes (0 = size for
     ``cfg`` itself); ``params`` carries the traced per-scenario scalars;
     ``scheme`` owns the ``extra`` slot (None = the default MatchRDMA
     block); ``channel`` owns the ``chan``/``retx_*`` slots (None = the
-    ideal channel — the slots stay empty)."""
+    ideal channel — the slots stay empty); ``round_wl`` is the workload
+    whose rounds the ``round_*`` slots track (None = no rounds — the slots
+    stay empty): the first round is released at each flow's start."""
     f = num_flows
     if delay_pad <= 0:
         delay_pad = _delay_steps(cfg)
@@ -368,12 +385,17 @@ def init_state(cfg: NetConfig, num_flows: int, params: NetParams = None,
             chan_delay_pad=delay_pad + _proc_steps(cfg)),
         chan=chan, retx_backlog=backlog, retx_line=retx_line,
         retx_inflight=retx_inflight,
+        **({} if round_wl is None else dict(
+            round_r=jnp.ones((f,), jnp.float32),
+            round_due_us=(jnp.asarray(round_wl.start_us)
+                          + jnp.asarray(round_wl.round_period_us)),
+            stall_us=z, round_late=z)),
     )
 
 
 def make_step_fn(cfg: NetConfig, wl: WorkloadParams, scheme,
                  period_slots: int = 0, params: NetParams = None,
-                 delay_pad: int = 0, channel=None):
+                 delay_pad: int = 0, channel=None, rounds: bool = False):
     """Build the per-step transition — the scheme-agnostic skeleton.
 
     ``wl``: the traced per-flow workload leaves. All per-scenario scalars
@@ -386,6 +408,9 @@ def make_step_fn(cfg: NetConfig, wl: WorkloadParams, scheme,
     models get the single channel hook point between the pipe exit and the
     destination OTN, and the engine's loss-repair path (notification ring,
     retransmit backlog served at ``Scheme.retx_rate``) activates.
+    ``rounds`` (STATIC, ``workload.has_rounds`` of the grid) builds the
+    round gate of flows with ``round_bytes > 0`` (docs/rounds.md); the
+    state must then carry the ``round_*`` slots (``init_state(round_wl=)``).
     """
     scheme = get_scheme(scheme)
     channel = get_channel_model(channel)
@@ -407,6 +432,11 @@ def make_step_fn(cfg: NetConfig, wl: WorkloadParams, scheme,
     # traced temperature replaces every knob-dependent hard select below by
     # a tempered blend; None (the default) leaves the hard jaxpr untouched.
     soft = params.soft_temp if cfg.soft_step else None
+    if rounds and cfg.soft_step:
+        raise ValueError(
+            "make_step_fn: round-gated flows (FlowSpec.round_bytes, "
+            "FlowSpec.round_period_us) have no soft relaxation — run them "
+            "with soft_step=False (docs/rounds.md)")
     # traced actual delay, clamped to the static ring allocation (mirrors
     # budget.init_channel) — an out-of-range wrap would silently alias
     # ring rows through JAX's index clamping instead of erroring
@@ -475,6 +505,10 @@ def make_step_fn(cfg: NetConfig, wl: WorkloadParams, scheme,
     period_us = jnp.asarray(wl.period_us)
     duty = jnp.asarray(wl.duty)
     active_mask = jnp.asarray(wl.active_mask)
+    if rounds:
+        round_b = jnp.asarray(wl.round_bytes)
+        round_p = jnp.asarray(wl.round_period_us)
+        is_round = round_b > 0
     rtt_us = jnp.where(is_inter > 0, 2.0 * d_steps * dt_us + 4.0, 4.0)
 
     ctx = SchemeCtx(
@@ -564,6 +598,40 @@ def make_step_fn(cfg: NetConfig, wl: WorkloadParams, scheme,
                     soft_gt(total_bytes, state.delivered, soft,
                             jnp.maximum(1e-3 * total_bytes, MTU)))
             active = started * in_period * not_done * active_mask
+
+            # ------------------------------------------ 1b. round gate
+            # Release round R+1 once it is due AND round R has landed: by
+            # last step's state, what the flow has yet to send of R * b
+            # and what it sent that is still in the network come to at
+            # most one MTU. They are read off the queues and the long
+            # haul, not off the cumulative delivered counter, whose
+            # roundoff grows with R (docs/rounds.md). The bytes posted,
+            # R * b - sent, cap what the sender sends below.
+            if rounds:
+                with jax.named_scope("netsim.rounds"):
+                    in_net = (state.q_src + state.q_leaf + state.inflight
+                              + (jnp.sum(state.q_dst, axis=0) if multi
+                                 else state.q_dst))
+                    if repair:
+                        in_net = (in_net + state.retx_backlog
+                                  + state.retx_inflight)
+                    if impaired:
+                        in_net = in_net + (
+                            jnp.sum(jax.vmap(channel.held_bytes)(state.chan),
+                                    axis=0)
+                            if multi else channel.held_bytes(state.chan))
+                    unsent = state.round_r * round_b - state.sent
+                    due = is_round & (t_us >= state.round_due_us)
+                    landed = unsent + in_net <= MTU
+                    release = due & landed
+                    late = release & (t_us - dt_us >= state.round_due_us)
+                    round_r = state.round_r + release.astype(jnp.float32)
+                    round_due = jnp.where(release, t_us + round_p,
+                                          state.round_due_us)
+                    stall_us = state.stall_us + jnp.where(
+                        due & ~landed, dt_us, 0.0)
+                    round_late = state.round_late + late.astype(jnp.float32)
+                    posted = jnp.maximum(round_r * round_b - state.sent, 0.0)
 
         # ------------------------------------------------ 2. delayed inputs
         with jax.named_scope("netsim.rings"):
@@ -678,6 +746,17 @@ def make_step_fn(cfg: NetConfig, wl: WorkloadParams, scheme,
             # -------------------------------------------- 4. sender rates
             win_avail = jnp.maximum(window - (state.sent - acked), 0.0)
             base_rate = jnp.minimum(win_avail / dt_s, nic)
+            if rounds:
+                with jax.named_scope("netsim.rounds"):
+                    # the transport sees only its work: the bytes posted
+                    # and, with loss repair, those awaiting retransmission.
+                    # The rate law starts from a window that holds no more
+                    # (the cap on new data below holds whatever it does)
+                    work = (posted + state.retx_backlog + retx_arr if repair
+                            else posted)
+                    base_rate = jnp.where(
+                        is_round, jnp.minimum(base_rate, work / dt_s),
+                        base_rate)
             with jax.named_scope("hook.sender_rate"):
                 rate = scheme.sender_rate(hctx, state, base_rate)
             # src-OTN -> sender PFC (1 step, from last-step queue)
@@ -716,6 +795,14 @@ def make_step_fn(cfg: NetConfig, wl: WorkloadParams, scheme,
         else:
             retx_send, retx_backlog = zero_f, zero_f
         with jax.named_scope("netsim.ack_rate"):
+            if rounds:
+                with jax.named_scope("netsim.rounds"):
+                    # whatever the rate law returned: no more new data
+                    # sent than posted (a cap on the rate, not on the
+                    # bytes, keeps the send / sent arithmetic of other
+                    # flows as is)
+                    rate = jnp.where(is_round,
+                                     jnp.minimum(rate, posted / dt_s), rate)
             send = rate * active * dt_s                # bytes this step
             sent = state.sent + send
 
@@ -883,6 +970,9 @@ def make_step_fn(cfg: NetConfig, wl: WorkloadParams, scheme,
             pause_line=pause_line, pause_dst=pause_dst, extra=fb.extra,
             chan=chan_new, retx_backlog=(retx_backlog if repair else None),
             retx_line=retx_line, retx_inflight=retx_inflight,
+            **({} if not rounds else dict(
+                round_r=round_r, round_due_us=round_due, stall_us=stall_us,
+                round_late=round_late)),
         )
         # per-flow byte conservation residual: everything the sender emitted
         # is either delivered or sitting in exactly one queue / the pipe —
@@ -1134,22 +1224,23 @@ def simulate(cfg: NetConfig, workload, scheme,
         validate_site_endpoints(cfg, wlp)   # host-side: stalls fail early
     return _run_traced(cfg, wlp, scheme, steps, period_slots,
                        delay_pad, history_slots, trace_mode, decimate,
-                       int(steps * WARMUP_FRAC), channel)
+                       int(steps * WARMUP_FRAC), channel,
+                       rounds=has_rounds(wlp))
 
 
 @partial(jax.jit, static_argnames=("scheme", "steps", "period_slots", "cfg",
                                    "delay_pad", "history_slots", "mode",
-                                   "decimate", "warm", "channel"))
+                                   "decimate", "warm", "channel", "rounds"))
 def _run_traced(cfg, wlp, scheme, steps, period_slots,
                 delay_pad=0, history_slots=0, mode="full", decimate=1,
-                warm=0, channel=None):
+                warm=0, channel=None, rounds=False):
     channel = get_channel_model(channel)
     f = wlp.is_inter.shape[0]
     state0 = init_state(cfg, f, delay_pad=delay_pad,
                         history_slots=history_slots, scheme=scheme,
-                        channel=channel)
+                        channel=channel, round_wl=wlp if rounds else None)
     step = make_step_fn(cfg, wlp, scheme, period_slots,
-                        delay_pad=delay_pad, channel=channel)
+                        delay_pad=delay_pad, channel=channel, rounds=rounds)
     return _scan_with_mode(step, scheme, channel, state0, steps, mode,
                            decimate, warm)
 
@@ -1210,7 +1301,8 @@ def simulate_batch(cfgs: Sequence[NetConfig], workload, scheme,
                    delay_pad: int = 0, history_slots: int = 0,
                    devices: Optional[Sequence] = None,
                    warm_steps: Optional[int] = None, channel=None,
-                   profile: Optional[dict] = None):
+                   profile: Optional[dict] = None,
+                   rounds: Optional[bool] = None):
     """Run a whole scenario grid as ONE vmapped computation.
 
     ``cfgs``: the per-scenario configs (distance / capacity / buffer grids);
@@ -1235,7 +1327,9 @@ def simulate_batch(cfgs: Sequence[NetConfig], workload, scheme,
     the launch through the AOT profiling path
     (``repro.netsim.obs.profiled_traced_batch``) — it is filled in place
     with the compile/execute wall-clock split and XLA memory figures
-    (docs/observability.md).
+    (docs/observability.md). ``rounds``: the static round-gate switch
+    (docs/rounds.md); None derives it from this batch's workload — a
+    chunked grid passes the whole grid's, so its launches share a program.
     """
     cfgs = list(cfgs)
     if not cfgs:
@@ -1253,6 +1347,8 @@ def simulate_batch(cfgs: Sequence[NetConfig], workload, scheme,
     with span("netsim.stack", profile):
         params = stack_net_params(cfgs)
         wlp = as_workload_batch(workload, len(cfgs))
+        if rounds is None:
+            rounds = has_rounds(wlp)
     if tmpl.is_multisite:
         from repro.netsim.topology import validate_site_endpoints
         validate_site_endpoints(tmpl, wlp)  # host-side: stalls fail early
@@ -1288,11 +1384,13 @@ def simulate_batch(cfgs: Sequence[NetConfig], workload, scheme,
             out = profiled_traced_batch(tmpl, params, wlp, scheme, steps,
                                         period_slots, delay_pad,
                                         history_slots, trace_mode, decimate,
-                                        warm, channel, profile)
+                                        warm, channel, profile,
+                                        rounds=rounds)
         else:
             out = _run_traced_batch(tmpl, params, wlp, scheme, steps,
                                     period_slots, delay_pad, history_slots,
-                                    trace_mode, decimate, warm, channel)
+                                    trace_mode, decimate, warm, channel,
+                                    rounds=rounds)
     if pad:
         with span("netsim.rows", profile):
             out = jax.tree.map(lambda x: x[:b], out)
@@ -1301,16 +1399,18 @@ def simulate_batch(cfgs: Sequence[NetConfig], workload, scheme,
 
 def _run_traced_batch_impl(cfg, params, wlp, scheme, steps, period_slots,
                            delay_pad, history_slots, mode="full",
-                           decimate=1, warm=0, channel=None):
+                           decimate=1, warm=0, channel=None, rounds=False):
     channel = get_channel_model(channel)
     f = wlp.is_inter.shape[-1]
 
     def one_scenario(p, w):
         state0 = init_state(cfg, f, params=p, delay_pad=delay_pad,
                             history_slots=history_slots, scheme=scheme,
-                            channel=channel)
+                            channel=channel,
+                            round_wl=w if rounds else None)
         step = make_step_fn(cfg, w, scheme, period_slots,
-                            params=p, delay_pad=delay_pad, channel=channel)
+                            params=p, delay_pad=delay_pad, channel=channel,
+                            rounds=rounds)
         return _scan_with_mode(step, scheme, channel, state0, steps, mode,
                                decimate, warm)
 
@@ -1325,7 +1425,8 @@ def _jit_traced_batch(donate_argnums=(), fun=_run_traced_batch_impl):
     return partial(jax.jit,
                    static_argnames=("cfg", "scheme", "steps", "period_slots",
                                     "delay_pad", "history_slots", "mode",
-                                    "decimate", "warm", "channel"),
+                                    "decimate", "warm", "channel",
+                                    "rounds"),
                    donate_argnums=donate_argnums)(fun)
 
 

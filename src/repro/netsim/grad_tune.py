@@ -65,6 +65,7 @@ from repro.netsim.fluid import (
     WARMUP_FRAC, _run_traced_batch_impl, as_workload_batch, batch_padding,
     batch_template, stack_net_params,
 )
+from repro.netsim.workload import has_rounds
 
 __all__ = [
     "KNOB_BOUNDS", "ADVERSARIAL_BOUNDS", "TuneResult", "tune",
@@ -175,6 +176,7 @@ def tune(knobs: Sequence[str] = ("budget_headroom",),
     n_steps = tmpl.horizon_steps(None)
     delay_pad, hist_slots = batch_padding(cfgs)
     wlp = as_workload_batch(wl, b)
+    rounds = has_rounds(wlp)
     params0 = stack_net_params(cfgs)
     warm = int(n_steps * WARMUP_FRAC)
     n_warm = max(n_steps - warm, 1)
@@ -191,7 +193,7 @@ def tune(knobs: Sequence[str] = ("budget_headroom",),
                for i, k in enumerate(knobs)})
         _, acc = _run_traced_batch_impl(
             tmpl, p, wlp, scheme, n_steps, 0, delay_pad, hist_slots,
-            mode="metrics", warm=warm, channel=channel)
+            mode="metrics", warm=warm, channel=channel, rounds=rounds)
         return -sign * surrogate_from_sums(acc.sum_s, n_warm)
 
     vg = jax.jit(jax.value_and_grad(loss))

@@ -28,6 +28,11 @@ Taxonomy (``EVENT_KINDS``):
   * ``scheme_budget_on`` / ``_off``  — the scheme's repair-budget reservation
                                        engaged / released (sdr_rdma: the
                                        congestion EWMA crossed 0.5)
+  * ``round_release``                — flows released their next round
+                                       (``value``: how many; rounds-on
+                                       workloads only, docs/rounds.md)
+  * ``round_late``                   — of those, releases made after their
+                                       due step (the round had not landed)
 
 Schemes add their own candidates through ``Scheme.emit_events`` (see
 ``docs/observability.md`` + ``docs/scheme-api.md``). Ring-off runs
@@ -54,6 +59,8 @@ EVENT_KINDS = {
     "scheme_brake": 6,
     "scheme_budget_on": 7,
     "scheme_budget_off": 8,
+    "round_release": 9,
+    "round_late": 10,
 }
 
 
@@ -130,7 +137,7 @@ def engine_event_candidates(ctx, prev_state, state, t):
     (pre, post) state pair and the traced step index, evaluated AROUND the
     step transition (never inside it, so ring-off runs keep the exact
     step jaxpr). Candidate COUNT is static: it depends only on compile-time
-    structure (link count, repair path, failure schedule)."""
+    structure (link count, repair path, failure schedule, rounds)."""
     multi = ctx.num_links > 1
     L = ctx.num_links
     t_f = jnp.asarray(t, jnp.float32)
@@ -181,6 +188,14 @@ def engine_event_candidates(ctx, prev_state, state, t):
                           down_now[li] & ~down_prev[li]))
             cands.append(("fail_exit", li, jnp.float32(1.0),
                           down_prev[li] & ~down_now[li]))
+
+    # round releases, all flows of the step in one event each (rounds-on
+    # workloads only — the slots exist only when the round gate does)
+    if state.round_r is not None:
+        n_rel = jnp.sum(state.round_r - prev_state.round_r)
+        n_late = jnp.sum(state.round_late - prev_state.round_late)
+        cands.append(("round_release", 0, n_rel, n_rel > 0))
+        cands.append(("round_late", 0, n_late, n_late > 0))
     return cands
 
 

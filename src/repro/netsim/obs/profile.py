@@ -175,7 +175,7 @@ def _shapes(tree):
 
 def profiled_traced_batch(cfg, params, wlp, scheme, steps, period_slots,
                           delay_pad, history_slots, mode, decimate, warm,
-                          channel, profile: dict):
+                          channel, profile: dict, rounds: bool = False):
     """Run the batched engine through an explicit lower → compile →
     execute pipeline, filling ``profile`` in place with:
 
@@ -193,7 +193,7 @@ def profiled_traced_batch(cfg, params, wlp, scheme, steps, period_slots,
 
     key = (cfg, scheme, steps, period_slots, delay_pad, history_slots,
            mode, decimate, warm, channel, jax.default_backend(),
-           _leaf_sig(params), _leaf_sig(wlp))
+           _leaf_sig(params), _leaf_sig(wlp), rounds)
     prog = _AOT_CACHE.get(key)
     cached = prog is not None
     t0 = time.perf_counter()
@@ -201,7 +201,7 @@ def profiled_traced_batch(cfg, params, wlp, scheme, steps, period_slots,
         hits = _persistent_cache_hits()
         args = (cfg, _shapes(params), _shapes(wlp), scheme, steps,
                 period_slots, delay_pad, history_slots, mode, decimate,
-                warm, channel)
+                warm, channel, rounds)
         compiled = fluid._jitted_traced_batch().lower(
             cfg, params, wlp, *args[3:]).compile()
         prog = _AOT_CACHE[key] = _Program(compiled, args)

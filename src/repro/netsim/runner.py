@@ -47,7 +47,7 @@ from repro.netsim.fluid import (
 )
 from repro.netsim.schemes import get_scheme
 from repro.netsim.workload import (
-    Workload, WorkloadParams, as_workload_batch, is_unbounded,
+    Workload, WorkloadParams, as_workload_batch, has_rounds, is_unbounded,
 )
 
 # Auto-chunk targets of the launch plan: a full-trace launch keeps its
@@ -107,6 +107,22 @@ def _flow_metrics(wl: WorkloadParams, final_np: dict):
     completion = np.where(n_finite > 0,
                           n_completed / np.maximum(n_finite, 1), 1.0)
     return goodput, avg_fct, completion
+
+
+def _round_cols(wl: WorkloadParams, final_np: dict, sim_us: float) -> dict:
+    """[B] round columns of a rounds-on grid (docs/rounds.md), from the
+    final state: over the inter-DC flows that run in rounds, the mean
+    rounds' worth delivered (``round_progress``) and the mean share of the
+    simulated time spent due but undelivered (``round_stall_frac``). Both
+    move continuously with time, so a release one step earlier or later
+    moves them by one step's worth at most."""
+    b = np.asarray(wl.round_bytes, np.float64)
+    mask = (np.asarray(wl.is_inter) > 0) & (b > 0)             # [B, F]
+    n = np.maximum(mask.sum(axis=1), 1)
+    got = final_np["delivered"] / np.maximum(b, 1.0)
+    stall = final_np["stall_us"] / sim_us
+    return {"round_progress": np.where(mask, got, 0.0).sum(axis=1) / n,
+            "round_stall_frac": np.where(mask, stall, 0.0).sum(axis=1) / n}
 
 
 def _assemble_rows(cfgs: Sequence[NetConfig], scheme_name: str,
@@ -209,9 +225,12 @@ def _failover_cols_from_traces(cfgs: Sequence[NetConfig], traces_np: dict,
 
 def _metrics_batch(cfgs: Sequence[NetConfig], wl: WorkloadParams,
                    scheme_name: str, final_np: dict, traces_np: dict,
-                   decimate: int = 1) -> List[Dict[str, float]]:
+                   decimate: int = 1, sim_us: Optional[float] = None
+                   ) -> List[Dict[str, float]]:
     """Fig. 3 metric set from materialized [B, T] traces in ONE vectorized
-    pass (``trace_mode="full"``/``"decimate"``)."""
+    pass (``trace_mode="full"``/``"decimate"``). ``sim_us``: the simulated
+    time, for the round columns of a rounds-on grid (``final_np`` then
+    carries ``stall_us``)."""
     steps = traces_np["q_dst"].shape[1]
     warm = int(steps * WARMUP_FRAC)
 
@@ -235,6 +254,8 @@ def _metrics_batch(cfgs: Sequence[NetConfig], wl: WorkloadParams,
             traces_np, warm, cfgs[0].dt_us * 1e-6, decimate))
     if cfgs[0].failure_len > 0:
         cols.update(_failover_cols_from_traces(cfgs, traces_np, decimate))
+    if "stall_us" in final_np:
+        cols.update(_round_cols(wl, final_np, sim_us))
     return _assemble_rows(cfgs, scheme_name, cols)
 
 
@@ -259,6 +280,8 @@ def _metrics_streaming(cfgs: Sequence[NetConfig], wl: WorkloadParams,
         "completion_frac": completion,
         "intra_thr_gbps": sums["thr_intra"] / n_warm * 8.0 / 1e9,
     }
+    if "stall_us" in final_np:
+        cols.update(_round_cols(wl, final_np, steps * cfgs[0].dt_us))
     extra = scheme.finalize_metrics(
         jax.tree.map(np.asarray, acc.scheme), steps, n_warm)
     # the channel accumulator also streams under the IDEAL channel when a
@@ -386,18 +409,20 @@ def _pad_chunk(cfgs, wlp: WorkloadParams, n: int):
     return list(cfgs) + [cfgs[-1]] * pad, wlp
 
 
-def _grid_static(cfgs, horizon_us, delay_pad: int, history_slots: int):
+def _grid_static(cfgs, horizon_us, delay_pad: int, history_slots: int,
+                 wlp: WorkloadParams):
     """The grid-wide static quantities every launch of a plan shares —
-    resolved horizon, scan length, warm cutoff, ring paddings — computed
-    ONCE over the WHOLE grid. Chunks must never re-derive them from their
-    own sub-grid, or chunked launches would stop sharing one compiled
-    program (and streaming normalizers would drift from the scan length)."""
+    resolved horizon, scan length, warm cutoff, ring paddings, the round
+    gate's switch (docs/rounds.md) — computed ONCE over the WHOLE grid.
+    Chunks must never re-derive them from their own sub-grid, or chunked
+    launches would stop sharing one compiled program (and streaming
+    normalizers would drift from the scan length)."""
     dp, hs = batch_padding(cfgs)
     horizon = (horizon_us if horizon_us is not None
                else max(c.horizon_us for c in cfgs))
     steps = batch_template(cfgs).horizon_steps(horizon)
     return (horizon, steps, int(steps * WARMUP_FRAC),
-            max(delay_pad, dp), max(history_slots, hs))
+            max(delay_pad, dp), max(history_slots, hs), has_rounds(wlp))
 
 
 # ---------------------------------------------------------------------------
@@ -566,7 +591,7 @@ def _run_launch(launch: _Launch, cfgs, wlp_np, grid_static, period_slots,
     the first violation of the first offending chunk. ``profile``: a dict
     routed to the AOT profiling path (filled in place with the launch's
     compile/execute split and memory figures — docs/observability.md)."""
-    horizon, steps, warm, delay_pad, history_slots = grid_static
+    horizon, steps, warm, delay_pad, history_slots, rounds = grid_static
     with span("netsim.stack", profile):
         sub_cfgs = cfgs[launch.lo:launch.hi]
         sub_wlp = WorkloadParams(*(v[launch.lo:launch.hi] for v in wlp_np))
@@ -578,7 +603,7 @@ def _run_launch(launch: _Launch, cfgs, wlp_np, grid_static, period_slots,
             trace_mode=trace_mode, decimate=decimate,
             delay_pad=delay_pad, history_slots=history_slots,
             devices=devices, warm_steps=warm, channel=channel,
-            profile=profile)
+            profile=profile, rounds=rounds)
     except Exception as e:  # noqa: BLE001 — filtered to OOM right below
         if not _is_oom_error(e) or n_real <= 1:
             raise
@@ -606,6 +631,10 @@ def _run_launch(launch: _Launch, cfgs, wlp_np, grid_static, period_slots,
         final_np = {"delivered": np.asarray(final.delivered),
                     "done_at_us": np.asarray(final.done_at_us)}
         wl_np = WorkloadParams(*(np.asarray(v) for v in sub_wlp))
+        if rounds:
+            final_np["stall_us"] = np.asarray(final.stall_us)
+            if profile is not None:
+                _round_counters(profile, wl_np, final, n_real)
         if trace_mode in ("metrics", "window"):
             acc = aux if trace_mode == "metrics" else aux.acc
             sub_rows = _metrics_streaming(sub_cfgs, wl_np, launch.scheme,
@@ -615,8 +644,22 @@ def _run_launch(launch: _Launch, cfgs, wlp_np, grid_static, period_slots,
             traces_np = {k: np.asarray(v) for k, v in aux.items()}
             sub_rows = _metrics_batch(
                 sub_cfgs, wl_np, launch.scheme.name, final_np, traces_np,
-                decimate if trace_mode == "decimate" else 1)
+                decimate if trace_mode == "decimate" else 1,
+                sim_us=steps * sub_cfgs[0].dt_us)
     return sub_rows[:n_real]
+
+
+def _round_counters(profile: dict, wl: WorkloadParams, final,
+                    n_real: int) -> None:
+    """A rounds-on launch's record counts, over its real cells' round
+    flows, the rounds released after each flow's first
+    (``rounds_released``) and those released after their due step
+    (``rounds_late``)."""
+    mask = (np.asarray(wl.round_bytes) > 0)[:n_real]
+    r = np.asarray(final.round_r)[:n_real]
+    late = np.asarray(final.round_late)[:n_real]
+    profile["rounds_released"] = int(np.where(mask, r - 1.0, 0.0).sum())
+    profile["rounds_late"] = int(np.where(mask, late, 0.0).sum())
 
 
 def _execute_plan(plan: Sequence[_Launch], cfgs, wlp: WorkloadParams,
@@ -808,7 +851,7 @@ def run_experiment_batch(cfgs: Sequence[NetConfig], workload, scheme,
     with span("netsim.stack"):
         wlp = as_workload_batch(workload, len(cfgs))
         grid_static = _grid_static(cfgs, horizon_us, delay_pad,
-                                   history_slots)
+                                   history_slots, wlp)
     n_dev = len(devices) if devices is not None else len(jax.devices())
     chunk = _auto_chunk_cells(grid_static[1], trace_mode, decimate,
                               chunk_cells, n_dev, cfgs[0].num_paths,
@@ -926,7 +969,7 @@ def sweep_grid(scenarios, workload=None, schemes=(),
     channel = get_channel_model(channel)
     with span("netsim.stack"):
         wlp = as_workload_batch(wl, len(cfgs))
-        grid_static = _grid_static(cfgs, horizon_us, 0, 0)
+        grid_static = _grid_static(cfgs, horizon_us, 0, 0, wlp)
     n_dev = len(devices) if devices is not None else len(jax.devices())
     chunk = _auto_chunk_cells(grid_static[1], trace_mode, decimate,
                               chunk_cells, n_dev, cfgs[0].num_paths,

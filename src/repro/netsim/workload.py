@@ -53,6 +53,13 @@ class FlowSpec:
     # contend at dst_site's leaf; their src_site is unused.
     src_site: int = 0
     dst_site: int = 1
+    # lock-step rounds (docs/rounds.md): a flow with round_bytes > 0 may
+    # have at most R * round_bytes sent once R rounds are released, and
+    # round R+1 is released no earlier than round_period_us after round R
+    # and not before round R is delivered. 0 = no rounds. Left out of the
+    # repr, so a round-free flow prints as before.
+    round_bytes: float = field(default=0.0, repr=False)
+    round_period_us: float = field(default=0.0, repr=False)
 
     @property
     def window(self) -> float:
@@ -80,6 +87,8 @@ class WorkloadParams(NamedTuple):
                                  # broadcast to cfg.num_paths by the engine)
     src_site: np.ndarray         # f32 — source site index (docs/sites.md)
     dst_site: np.ndarray         # f32 — destination site index
+    round_bytes: np.ndarray      # f32 — bytes per round (0 = no rounds)
+    round_period_us: np.ndarray  # f32 — earliest spacing of two releases
 
     @classmethod
     def of(cls, workload: "Workload", pad_to: int = 0,
@@ -120,6 +129,8 @@ class WorkloadParams(NamedTuple):
             route=route,
             src_site=_p(a["src_site"]),
             dst_site=_p(a["dst_site"]),
+            round_bytes=_p(a["round_bytes"]),
+            round_period_us=_p(a["round_period_us"]),
         )
 
     @property
@@ -132,6 +143,13 @@ class WorkloadParams(NamedTuple):
 
 
 WorkloadLike = Union["Workload", WorkloadParams]
+
+
+def has_rounds(wl: WorkloadParams) -> bool:
+    """Whether any flow of a (stacked) workload runs in rounds: the STATIC
+    switch of the round gate (docs/rounds.md). Read from host values, once
+    per grid, so every launch of a plan shares one program."""
+    return bool(np.any(np.asarray(wl.round_bytes) > 0))
 
 
 def stack_workload_params(workloads: Sequence["Workload"],
@@ -197,6 +215,9 @@ class Workload:
             "duty": np.array([x.duty for x in f], np.float32),
             "src_site": np.array([x.src_site for x in f], np.float32),
             "dst_site": np.array([x.dst_site for x in f], np.float32),
+            "round_bytes": np.array([x.round_bytes for x in f], np.float32),
+            "round_period_us": np.array([x.round_period_us for x in f],
+                                        np.float32),
         }
 
     def params(self, pad_to: int = 0) -> WorkloadParams:

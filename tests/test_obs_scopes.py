@@ -22,8 +22,8 @@ from repro.netsim import FailureSchedule, fluid, read_manifest, sweep_grid
 from repro.netsim.obs import profile
 from repro.netsim.schemes import Scheme, get_scheme
 from repro.netsim.schemes.matchrdma import MatchRdmaScheme
-from repro.netsim.workload import (WorkloadParams, as_workload_batch,
-                                   congestion_workload)
+from repro.netsim.workload import (FlowSpec, Workload, WorkloadParams,
+                                   as_workload_batch, congestion_workload)
 
 HORIZON_US = 4_000.0
 DISTANCES = (1.0, 300.0)
@@ -40,10 +40,10 @@ def _workload():
                                horizon_us=HORIZON_US)
 
 
-def _compiled_scopes(cfgs, scheme, channel=None):
+def _compiled_scopes(cfgs, scheme, channel=None, wl=None, rounds=False):
     """(``hlo_scopes``, HLO text) of the batch program compiled for
-    ``cfgs``."""
-    wlp = as_workload_batch(_workload(), len(cfgs))
+    ``cfgs`` (and ``wl``, by default ``_workload()``)."""
+    wlp = as_workload_batch(wl or _workload(), len(cfgs))
     wlp = WorkloadParams(*(jnp.asarray(np.asarray(v)) for v in wlp))
     params = stack_net_params(cfgs)
     params = type(params)(*(jnp.asarray(np.asarray(v)) for v in params))
@@ -52,7 +52,7 @@ def _compiled_scopes(cfgs, scheme, channel=None):
     pad, hist = fluid.batch_padding(cfgs)
     compiled = fluid._jitted_traced_batch().lower(
         tmpl, params, wlp, get_scheme(scheme), steps, 0, pad, hist,
-        "metrics", 1, steps // 10, channel).compile()
+        "metrics", 1, steps // 10, channel, rounds).compile()
     text = compiled.as_text()
     return profile.hlo_scopes(text), text
 
@@ -93,6 +93,21 @@ def test_impaired_channel_and_failover_carry_channel_and_every_hook():
     assert "ack_view" in overridden
     assert overridden - {"ack_view"} <= hooks
     assert "retx_rate" in hooks       # the inherited hook runs under repair
+
+
+def test_round_gated_flows_carry_the_rounds_phase():
+    """Round-gated flows (docs/rounds.md) add the ``rounds`` phase to the
+    default program's; without them the gate is not built at all (the
+    default-program test above)."""
+    rounds = tuple(FlowSpec(True, 1 << 20, 16, round_bytes=1.5e6,
+                            round_period_us=300.0) for _ in range(4))
+    wl = Workload(rounds + _workload().flows[4:])
+    scopes, text = _compiled_scopes(
+        [NetConfig(distance_km=d) for d in DISTANCES], "matchrdma", wl=wl,
+        rounds=True)
+    assert _phases(scopes) == PHASES - {"channel"} | {"rounds",
+                                                      profile.OTHER}
+    assert "netsim.rounds" in text
 
 
 def test_hlo_scopes_on_a_made_up_module():
