@@ -307,29 +307,7 @@ class NetParams(NamedTuple):
     @classmethod
     def of(cls, cfg: "NetConfig") -> "NetParams":
         import jax.numpy as jnp
-        scalars = tuple(jnp.float32(v) for v in (
-            cfg.one_way_delay_us, cfg.otn_capacity_gbps, cfg.dst_dc_gbps,
-            cfg.nic_gbps, cfg.pfc_xoff_kb, cfg.pfc_xon_kb,
-            cfg.otn_buffer_bdp_frac, cfg.ecn_kmin_kb, cfg.ecn_kmax_kb,
-            cfg.queue_thresh_kb, cfg.budget_floor_mbps,
-            cfg.budget_headroom, cfg.geopipe_credit_bdp_frac,
-            cfg.sdr_window_bdp_frac, cfg.sdr_ack_coalesce_us,
-            cfg.sdr_retx_budget_frac, cfg.loss_rate, cfg.loss_burst_len,
-            cfg.jitter_us, cfg.flap_period_us, cfg.flap_depth,
-            cfg.rdmacell_token_bucket_us, cfg.rdmacell_rob_limit_mb,
-            cfg.slot_us, cfg.soft_temp))
-        import numpy as np
-        return cls(*scalars,
-                   link_delay_us=jnp.asarray(
-                       np.float32(cfg.path_delays_us())),
-                   link_cap_gbps=jnp.asarray(
-                       np.float32(cfg.path_caps_gbps())),
-                   link_thresh_kb=jnp.asarray(
-                       np.float32(cfg.path_pfc_kb())),
-                   chan_schedule=jnp.asarray(cfg.schedule_array()),
-                   chan_sched_dt_us=jnp.float32(
-                       cfg.channel_schedule_dt_us),
-                   fail_windows=jnp.asarray(cfg.failure_array()))
+        return cls(*(jnp.asarray(v) for v in _host_leaves(cfg)))
 
     def delay_steps(self, dt_us: float):
         """Traced step count of the long-haul delay (>= 1)."""
@@ -338,10 +316,36 @@ class NetParams(NamedTuple):
             jnp.round(self.one_way_delay_us / dt_us).astype(jnp.int32), 1)
 
 
+def _host_leaves(cfg: "NetConfig") -> tuple:
+    """One scenario's ``NetParams`` leaves, in field order, as host float32
+    NumPy values: the values (and avals) ``NetParams.of`` wraps."""
+    import numpy as np
+    scalars = (
+        cfg.one_way_delay_us, cfg.otn_capacity_gbps, cfg.dst_dc_gbps,
+        cfg.nic_gbps, cfg.pfc_xoff_kb, cfg.pfc_xon_kb,
+        cfg.otn_buffer_bdp_frac, cfg.ecn_kmin_kb, cfg.ecn_kmax_kb,
+        cfg.queue_thresh_kb, cfg.budget_floor_mbps,
+        cfg.budget_headroom, cfg.geopipe_credit_bdp_frac,
+        cfg.sdr_window_bdp_frac, cfg.sdr_ack_coalesce_us,
+        cfg.sdr_retx_budget_frac, cfg.loss_rate, cfg.loss_burst_len,
+        cfg.jitter_us, cfg.flap_period_us, cfg.flap_depth,
+        cfg.rdmacell_token_bucket_us, cfg.rdmacell_rob_limit_mb,
+        cfg.slot_us, cfg.soft_temp)
+    return (*(np.float32(v) for v in scalars),
+            np.float32(cfg.path_delays_us()),
+            np.float32(cfg.path_caps_gbps()),
+            np.float32(cfg.path_pfc_kb()),
+            cfg.schedule_array(),
+            np.float32(cfg.channel_schedule_dt_us),
+            cfg.failure_array())
+
+
 def stack_net_params(cfgs: Sequence["NetConfig"]) -> NetParams:
-    """Stack per-scenario params into one [B]-leading pytree for vmap."""
-    import jax
-    import jax.numpy as jnp
+    """Stack per-scenario params into one [B]-leading pytree for vmap.
+
+    The leaves are host NumPy arrays, built without a device op; the
+    launch puts each one on the device once."""
+    import numpy as np
     lens = {c.schedule_len for c in cfgs}
     if len(lens) > 1:
         raise ValueError(
@@ -357,8 +361,8 @@ def stack_net_params(cfgs: Sequence["NetConfig"]) -> NetParams:
             f"table is a stacked traced leaf, so every scenario must carry "
             f"the same number of windows (pad with no-op (0, 0) windows; "
             f"repro.netsim.failures.FailureSchedule does this)")
-    return jax.tree.map(lambda *xs: jnp.stack(xs),
-                        *[NetParams.of(c) for c in cfgs])
+    return NetParams(*(np.stack(xs) for xs in
+                       zip(*(_host_leaves(c) for c in cfgs))))
 
 
 # NetConfig fields whose values reach the batched step ONLY through the

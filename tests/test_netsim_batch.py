@@ -124,6 +124,48 @@ def test_stack_net_params_shapes():
     assert len(single) == len(stacked)
 
 
+HOST_STACK_GRIDS = {
+    "fig3cd_l1": [NetConfig(distance_km=d)
+                  for d in (1.0, 10.0, 50.0, 100.0, 300.0, 500.0, 1000.0)],
+    "paths3": [NetConfig(distance_km=d, num_paths=3,
+                         path_delay_scale=(1.0, 2.0, 4.0),
+                         path_cap_frac=(0.5, 0.3, 0.2),
+                         path_thresh_kb=(100.0, 200.0, 300.0))
+               for d in (1.0, 300.0)],
+    "schedule": [NetConfig(distance_km=d, channel_schedule_dt_us=50.0,
+                           channel_schedule=(((0.0, 0.1, 1.0),
+                                              (0.01, 0.0, 0.5)),))
+                 for d in (1.0, 300.0)],
+    "failures": [NetConfig(distance_km=d, failure_schedule=(
+        ((1000.0 + d, 2000.0), (0.0, 0.0)),)) for d in (1.0, 300.0)],
+}
+
+
+@pytest.mark.parametrize("grid", sorted(HOST_STACK_GRIDS))
+def test_stack_net_params_on_host_matches_device_stack(grid):
+    """The stacked leaves are host NumPy arrays, byte for byte the device
+    stack of the per-cell ``NetParams.of`` leaves, and put on the device
+    they carry the same avals: the compiled program does not change."""
+    import jax
+    import jax.numpy as jnp
+    cfgs = HOST_STACK_GRIDS[grid]
+    host = stack_net_params(cfgs)
+    dev = [jnp.stack(xs) for xs in zip(*(NetParams.of(c) for c in cfgs))]
+
+    def aval(x):
+        a = jax.typeof(x)
+        return a.shape, a.dtype, a.weak_type
+
+    for name, h, d in zip(NetParams._fields, host, dev):
+        assert isinstance(h, np.ndarray) and not isinstance(h, jax.Array), \
+            (name, type(h))
+        assert aval(jnp.asarray(h)) == aval(d) \
+            == (h.shape, jnp.float32, False), name
+        d = np.asarray(d)
+        assert (h.dtype, h.shape) == (d.dtype, d.shape), name
+        assert h.tobytes() == d.tobytes(), name
+
+
 def test_batch_rejects_mixed_static_structure():
     """Any non-traced field varying across a batch must fail loudly, not
     silently simulate every cell with one cell's value."""

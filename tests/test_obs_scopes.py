@@ -192,6 +192,25 @@ def test_launch_records_carry_host_spans_and_cache_state(tiny_sweep):
     assert {ln["persistent_cache"] for ln in second} == {"in_process"}
 
 
+def test_host_stacked_sweep_rows_match_run_experiment(tiny_sweep):
+    """The sweep whose launches stack their parameters on the host returns,
+    cell by cell, the rows a one-cell ``run_experiment`` returns."""
+    from repro.netsim import batch_padding, run_experiment
+    cfgs = [NetConfig(distance_km=d) for d in DISTANCES]
+    pad, hist = batch_padding(cfgs)
+    rows = iter(tiny_sweep["rows"][0])
+    for cfg in cfgs:
+        for scheme in ("matchrdma", "dcqcn"):
+            row = next(rows)
+            ref = run_experiment(cfg, _workload(), get_scheme(scheme),
+                                 HORIZON_US, delay_pad=pad,
+                                 history_slots=hist, trace_mode="metrics")
+            assert (row["scheme"], row["distance_km"]) == (scheme,
+                                                           cfg.distance_km)
+            np.testing.assert_equal({k: row[k] for k in ref}, ref,
+                                    err_msg=f"{scheme} {cfg.distance_km}")
+
+
 def test_trace_scopes_read_the_executable_and_change_no_row(tiny_sweep):
     """An executable compiled by this program carries its scopes: the map
     is read from it, with no compile, and the launches run on as
